@@ -25,6 +25,8 @@ import sys
 
 import coverage as coverage_bench
 
+from repro.core import compile_cache
+
 BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "coverage_baseline.json")
 
@@ -125,4 +127,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     sys.exit(main())

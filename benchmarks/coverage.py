@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core import UnsupportedKernel, backend_names
+from repro.core import UnsupportedKernel, backend_names, compile_cache
 from repro.core.cuda_suite import build_suite, run_entry
 
 #: the paper's Table II Rodinia coverage: CuPBoP vs the best prior
@@ -94,4 +94,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     main()

@@ -10,7 +10,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import time_call
-from repro.core import cache_clear
+from repro.core import cache_clear, compile_cache
 from repro.core.cuda_suite import build_suite, run_entry
 
 
@@ -38,4 +38,5 @@ def main(scale: int = 4):
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     main()

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import time_call
+from repro.core import compile_cache
 from repro.core import grain as grain_mod
 from repro.core.cuda_suite import make_histogram, make_vecadd
 
@@ -61,4 +62,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     main()

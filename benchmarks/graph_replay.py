@@ -24,7 +24,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import Stream, api
+from repro.core import Stream, api, compile_cache
 from repro.core.kernel import KernelDef
 
 N_STEPS = 10
@@ -140,4 +140,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     main()

@@ -25,7 +25,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import Policy, Stream, api
+from repro.core import Policy, Stream, api, compile_cache
 from repro.core.cuda_suite import make_vecadd
 
 N_LAUNCH = 1000
@@ -144,4 +144,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     main()

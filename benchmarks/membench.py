@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import Stream, api, memory
+from repro.core import Stream, api, compile_cache, memory
 from repro.core.cuda_suite import build_suite, run_entry
 from repro.core.kernel import ChainStats
 
@@ -177,4 +177,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     main()

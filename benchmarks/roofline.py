@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import api, cuda_suite, memory, optimize, packing
+from repro.core import (api, compile_cache, cuda_suite, memory, optimize,
+                        packing)
 from repro.core.dim3 import Dim3
 
 #: kernels with proven fusion regions (pixel_pipeline 2 pairs = one whole-
@@ -245,4 +246,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     sys.exit(main())

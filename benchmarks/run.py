@@ -19,6 +19,7 @@ import traceback
 
 from benchmarks import (coverage, endtoend, grain_sweep, graph_replay,
                         launch_overhead, reorder, roofline)
+from repro.core import compile_cache
 
 # argparse-based benchmarks get an explicit empty argv so they don't
 # swallow run.py's own command line
@@ -54,4 +55,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     main()

@@ -31,7 +31,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import api
+from repro.core import api, compile_cache
 from repro.core.cuda_suite import build_suite
 from repro.serve import KernelService, ServiceOverloaded
 
@@ -40,7 +40,7 @@ from repro.serve import KernelService, ServiceOverloaded
 #: and are exercised by tests, not the throughput benchmark)
 ROSTER = ["vecadd", "softmax_row", "reduce_shared", "stencil1d",
           "scan_block", "pixel_pipeline"]
-BACKEND = "loop"
+BACKEND = "vector"
 
 
 def build_requests(entries, n: int, seed: int = 0):
@@ -241,4 +241,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.use_jax_cache()
     raise SystemExit(main())

@@ -12,7 +12,10 @@ Every device count runs in its **own subprocess** with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``: that is how a CPU
 host gets an XLA worker pool (there is no way to resize it after jax
 initializes), and it keeps the 1-device baseline free of the multi-device
-client's extra threads.  Each child times several repetitions and keeps
+client's extra threads.  The children force ``JAX_PLATFORMS=cpu``, so
+this is a CPU benchmark and never a chip path; on a four-chip host,
+``chip_smoke.py --four-chips`` drives ``shard_vector`` on the real mesh.
+Each child times several repetitions and keeps
 the best (shared CI runners are noisy; the minimum is the least-disturbed
 estimate of the machine's capability).
 
@@ -64,8 +67,9 @@ def child(devices: int, n_blocks: int, block: int, steps: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core import api
+    from repro.core import api, compile_cache
 
+    compile_cache.use_jax_cache()
     assert jax.device_count() >= devices, (
         f"child asked for {devices} devices but the process has "
         f"{jax.device_count()}; XLA_FLAGS was not honored")

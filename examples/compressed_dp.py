@@ -17,7 +17,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.compression import compressed_psum, dcn_bytes
@@ -51,12 +50,12 @@ def step(w, err, x, y):
 
     # The error-feedback residual is *per-pod* state (each pod keeps its own
     # quantization error), so it carries a leading pod axis through
-    # shard_map.  check_rep=False: the reduced gradient IS replicated (psum)
-    # but the static rep-check cannot infer that through the int8 round-trip.
-    g, err = shard_map(per_pod, mesh=mesh,
-                       in_specs=(P(), P("pod"), P("pod"), P("pod")),
-                       out_specs=(P(), P("pod")),
-                       check_rep=False)(w, err, x, y)
+    # shard_map.  check_vma=False: the reduced gradient IS replicated (psum)
+    # but the static check cannot infer that through the int8 round-trip.
+    g, err = jax.shard_map(per_pod, mesh=mesh,
+                           in_specs=(P(), P("pod"), P("pod"), P("pod")),
+                           out_specs=(P(), P("pod")),
+                           check_vma=False)(w, err, x, y)
     return w - LR * g, err
 
 

@@ -16,7 +16,7 @@ CUDA-faithful API surface:
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import Policy, Runtime, Stream, backend_names
+from repro.core import Policy, Runtime, Stream, UnsupportedKernel, backend_names
 from repro.core.cuda_suite import (
     make_reverse,
     make_stencil2d,
@@ -31,9 +31,13 @@ vecadd = make_vecadd(n)
 a = np.random.default_rng(0).standard_normal(n, dtype=np.float32)
 b = np.random.default_rng(1).standard_normal(n, dtype=np.float32)
 for backend in ("loop", "vector", "pallas"):
-    out = vecadd[grid, block].on(backend=backend, grain="aggressive",
-                                 pool=4)(
-        a=jnp.asarray(a), b=jnp.asarray(b), c=jnp.zeros(n, jnp.float32))
+    try:
+        out = vecadd[grid, block].on(backend=backend, grain="aggressive",
+                                     pool=4)(
+            a=jnp.asarray(a), b=jnp.asarray(b), c=jnp.zeros(n, jnp.float32))
+    except UnsupportedKernel as e:     # e.g. Mosaic's refusal on a TPU
+        print(f"vecadd[{backend:6s}] unsupported: {e}")
+        continue
     ok = np.allclose(np.asarray(out["c"]), a + b)
     print(f"vecadd[{backend:6s}] correct={ok}")
 print("registered backends:", backend_names())

@@ -59,6 +59,7 @@ import os
 import sys
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -607,7 +608,10 @@ def _interpret_block(kernel: KernelDef, bid: int, *, block: Dim3, grid: Dim3,
     for si, stage in enumerate(kernel.stages):
         for rec in recs.values():
             rec.begin_stage()
-        st = stage(ctx, st)
+        # jit would abstractify a TrackedArray argument, which JAX refuses
+        # for ``__jax_array__`` objects; op by op, jnp converts it first
+        with jax.disable_jit():
+            st = stage(ctx, st)
         check_priv_chunk(st.priv, block.size, kernel.name, si)
         st = st._replace(shared=_rewrap("shared", st.shared, recs, block, si),
                          glob=_rewrap("glob", st.glob, recs, block, si))

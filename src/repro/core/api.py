@@ -29,6 +29,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import os
+import traceback
 import weakref
 from typing import Any
 
@@ -39,7 +40,7 @@ from repro.core import backends as backends_mod
 from repro.core import compile_cache
 from repro.core import grain as grain_mod
 from repro.core import memory as memory_mod
-from repro.core import packing
+from repro.core import packing, pallas_emit
 from repro.core.backends import backend_names, get_backend, register_backend
 from repro.core.dim3 import Dim3
 from repro.core.kernel import CompiledKernel, KernelDef, UnsupportedKernel
@@ -229,18 +230,43 @@ def _compile(kernel: KernelDef, backend: str, grid: Dim3, block: Dim3,
                 interpret, devices, shard_axis, donate_idx)
     # surface UnsupportedKernel eagerly (coverage probes rely on this)
     jax.eval_shape(fn, *leaves)
+    if backend == "pallas" and not interpret:
+        mosaic_compile(fn, leaves)
     if _DISK is not None and _DISK.store(akey, fn, leaves):
         _STATS.disk_stores += 1
     return CompiledKernel(kernel=kernel, backend=backend, grid=grid,
                           block=block, key=key, fn=fn, source="trace")
 
 
+def mosaic_compile(fn, leaves) -> None:
+    """Compile a non-interpret ``pallas`` entry ahead of time.
+
+    Mosaic refuses kernels at lowering, after ``jax.eval_shape`` has
+    passed, so without this a refused kernel would read as supported and
+    then crash at dispatch.  ``leaves`` may be arrays or
+    ``ShapeDtypeStruct``s (with the target device's sharding).  With the
+    persistent compilation cache on, the dispatch that follows reuses
+    this compile.
+    """
+    try:
+        fn.lower(*leaves).compile()
+    except Exception as e:  # noqa: BLE001 - every refusal means unsupported
+        msg = str(e).strip().splitlines()[0] if str(e).strip() else ""
+        if not msg:         # bare asserts inside the Mosaic lowering
+            frame = traceback.extract_tb(e.__traceback__)[-1]
+            msg = f"at {os.path.basename(frame.filename)}:{frame.lineno}"
+        raise UnsupportedKernel(
+            f"pallas/Mosaic: {type(e).__name__}: {msg}") from e
+
+
 def _entry_for(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
-               backend: str, grain, dyn_shared, interpret: bool,
+               backend: str, grain, dyn_shared, interpret: bool | None,
                pool, devices=None,
                shard_axis: str = "blocks") -> tuple[CompiledKernel, tuple]:
     """Resolve the launch specialization: memory hit, disk hit, or compile."""
     grain = _resolve_grain(kernel, grain, pool, grid.size)
+    # resolved before keying, so the cache holds what actually ran
+    interpret = pallas_emit.resolve_interpret(interpret)
     # single-device backends ignore the device options, so normalize them
     # out of the key - launch(backend="loop", devices=4) must share the
     # specialization (and disk artifact) of the plain launch
@@ -301,7 +327,7 @@ def _optimize_enabled(optimize) -> bool:
 
 
 def _launch(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
-            backend: str, grain, dyn_shared, interpret: bool,
+            backend: str, grain, dyn_shared, interpret: bool | None,
             pool, devices=None, shard_axis: str = "blocks",
             sanitize: bool | None = None,
             optimize: bool | None = None) -> dict:
@@ -335,7 +361,7 @@ def _launch(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
 
 def compiled(kernel: KernelDef, *, grid, block, args: dict,
              backend: str = "vector", grain: int | str = 1,
-             dyn_shared: int | None = None, interpret: bool = True,
+             dyn_shared: int | None = None, interpret: bool | None = None,
              pool: int | None = None, devices: int | None = None,
              shard_axis: str = "blocks",
              optimize: bool | None = None) -> CompiledKernel:
@@ -387,7 +413,7 @@ class LaunchConfig:
     stream: Any = None
     backend: str = "vector"
     grain: int | str = 1
-    interpret: bool = True
+    interpret: bool | None = None
     pool: int | None = None
     devices: int | None = None
     shard_axis: str = "blocks"
@@ -438,7 +464,7 @@ class LaunchConfig:
 
 def launch(kernel: KernelDef, *, grid, block, args: dict,
            backend: str = "vector", grain: int | str = 1,
-           dyn_shared: int | None = None, interpret: bool = True,
+           dyn_shared: int | None = None, interpret: bool | None = None,
            pool: int | None = None, devices: int | None = None,
            shard_axis: str = "blocks",
            sanitize: bool | None = None,
@@ -486,7 +512,7 @@ def _build_batch(kernel: KernelDef, backend: str, grid: Dim3, block: Dim3,
 
 def launch_batch(kernel: KernelDef, *, grid, block, args_list: list[dict],
                  backend: str = "vector", grain: int | str = 1,
-                 dyn_shared: int | None = None, interpret: bool = True,
+                 dyn_shared: int | None = None, interpret: bool | None = None,
                  pool: int | None = None,
                  sanitize: bool | None = None,
                  optimize: bool | None = None) -> list[dict]:
@@ -534,6 +560,7 @@ def launch_batch(kernel: KernelDef, *, grid, block, args_list: list[dict],
             f"devices; stacked request batching is single-device only - "
             f"dispatch these requests independently")
     grain = _resolve_grain(kernel, grain, pool, grid.size)
+    interpret = pallas_emit.resolve_interpret(interpret)
     packed, treedef0, shapes0 = [], None, None
     for i, a in enumerate(args_list):
         leaves, treedef = packing.pack(
@@ -563,6 +590,8 @@ def launch_batch(kernel: KernelDef, *, grid, block, args_list: list[dict],
                           treedef0, interpret)
         # surface UnsupportedKernel eagerly, as the single-launch path does
         jax.eval_shape(fn, *stacked)
+        if backend == "pallas" and not interpret:
+            mosaic_compile(fn, stacked)
         entry = CompiledKernel(kernel=kernel, backend=backend, grid=grid,
                                block=block, key=key, fn=fn, source="trace")
         per_kernel[key] = entry

@@ -21,6 +21,11 @@ default directory so test/CI runs never write outside their sandbox unless
 asked to.  Serialization is best-effort: a kernel whose lowering cannot be
 exported (or a corrupt/unwritable cache file) degrades to in-memory-only
 caching, never to an error.
+
+This tier stores traced programs, not XLA executables.  Those live in
+JAX's own persistent compilation cache, which :func:`use_jax_cache` turns
+on; entry points (``chip_smoke.py``, ``python -m repro.launch.serve``,
+the ``benchmarks/`` scripts) call it, imports never do.
 """
 from __future__ import annotations
 
@@ -30,13 +35,31 @@ import tempfile
 from typing import Callable
 
 import jax
-
-try:                                 # submodule: not reachable as jax.export
-    from jax import export as _jax_export
-except ImportError:                  # pragma: no cover - very old jax
-    _jax_export = None
+from jax import export as _jax_export   # submodule: not reachable as jax.export
 
 CACHE_FORMAT_VERSION = 1
+
+#: JAX's compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed path, because the path is part of what a later run must find
+JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_jax_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX; otherwise the
+    cache goes to :data:`JAX_CACHE_DIR`.  Suite kernels compile in well
+    under JAX's default one-second persistence threshold, so every compile
+    is kept.  Call before the first compile: JAX reads these settings once.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = JAX_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def artifact_key(fingerprint: str, backend: str, grid, block, grain,
@@ -77,8 +100,6 @@ class DiskCache:
         The returned callable has the same leaves->pytree signature the
         traced function had; wrap it in ``jax.jit`` for dispatch caching.
         """
-        if _jax_export is None:
-            return None
         try:
             with open(self._file(key), "rb") as f:
                 blob = f.read()
@@ -99,8 +120,6 @@ class DiskCache:
         failure (non-exportable primitive, read-only dir) is swallowed -
         the in-memory cache still holds the entry.
         """
-        if _jax_export is None:
-            return False
         try:
             blob = _jax_export.export(jax.jit(fn))(*leaves).serialize()
             os.makedirs(self.path, exist_ok=True)

@@ -35,7 +35,7 @@ makes that a first-class, machine-checkable property of the repo:
   artifact).  ``--inject-disagreement`` registers a deliberately broken
   backend so CI can prove the gate trips.
 
-f64 cells run under ``jax.experimental.enable_x64`` so the sweep works in
+f64 cells run under ``jax.enable_x64`` so the sweep works in
 a default-configured process without flipping global state for f32 cells.
 """
 from __future__ import annotations
@@ -394,7 +394,8 @@ def _tol_for(entry: SuiteEntry, case: ConformanceCase, tag: str) -> float:
                                                    DTYPE_TOL["f32"])
 
 
-def _oracle_check(out, want, tol: float) -> tuple[float, list[str]]:
+def oracle_check(out, want, tol: float) -> tuple[float, list[str]]:
+    """Compare outputs with the NumPy oracle: (max |err|, mismatches)."""
     bad, max_err = [], 0.0
     for k, v in want.items():
         got, v = np.asarray(out[k]), np.asarray(v)
@@ -434,7 +435,7 @@ def run_cell(entry: SuiteEntry, case: ConformanceCase, backend: str,
     if entry.chain is None:
         geo_kw = {"grid": grid, "block": block}
     try:
-        ctx = (jax.experimental.enable_x64() if tag == "f64"
+        ctx = (jax.enable_x64(True) if tag == "f64"
                else contextlib.nullcontext())
         with ctx:
             out, want = run_entry(entry, backend, grain=grain,
@@ -443,7 +444,7 @@ def run_cell(entry: SuiteEntry, case: ConformanceCase, backend: str,
                                   optimize=True if mode == "optimized"
                                   else None, **geo_kw)
         tol = _tol_for(entry, case, tag)
-        cell.max_abs_err, bad = _oracle_check(out, want, tol)
+        cell.max_abs_err, bad = oracle_check(out, want, tol)
         if bad:
             cell.status = "fail"
             cell.detail = "oracle mismatch: " + "; ".join(bad)
@@ -525,7 +526,7 @@ def run_matrix(cases: list[ConformanceCase] | None = None,
                 e = entries[tag]
                 geo = ({} if e.chain is not None
                        else {"grid": grid, "block": block})
-                ctx = (jax.experimental.enable_x64() if tag == "f64"
+                ctx = (jax.enable_x64(True) if tag == "f64"
                        else contextlib.nullcontext())
                 with ctx:
                     out, _ = run_entry(e, anchor_backend, grain=grain, **geo)

@@ -50,10 +50,12 @@ from typing import Callable
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import memory, rodinia_io
 from repro.core.api import launch
-from repro.core.kernel import ChainStats, ChainStep, KernelDef, LaunchChain
+from repro.core.kernel import (ChainStats, ChainStep, KernelDef, LaunchChain,
+                               expf, logf)
 
 OOB = 1 << 30  # out-of-bounds sentinel for mode="drop" stores
 
@@ -218,7 +220,9 @@ def make_matmul_tiled(m: int, n: int, k: int, tile: int = 8,
     def compute(ctx, st):
         ty, tx, _, _ = coords(ctx)
         sa, sb = st.shared["sa"], st.shared["sb"]
-        acc = st.priv["acc"] + jnp.einsum("ti,it->t", sa[ty, :], sb[:, tx])
+        # HIGHEST: a CUDA f32 FMA is f32; the TPU default runs bf16 passes
+        acc = st.priv["acc"] + jnp.einsum("ti,it->t", sa[ty, :], sb[:, tx],
+                                          precision=lax.Precision.HIGHEST)
         return st.with_priv({"acc": acc})
 
     def store(ctx, st):
@@ -321,7 +325,7 @@ def make_softmax_row(block: int, dtype=jnp.float32) -> KernelDef:
     def exps(ctx, st):
         s = st.shared["s"]
         m = jnp.max(s)                       # every thread reads all of shared
-        p = jnp.exp(s[ctx.tid] - m)
+        p = expf(s[ctx.tid] - m)
         return st.set_shared(p=st.shared["p"].at[ctx.tid].set(p))
 
     def normalize(ctx, st):
@@ -424,7 +428,7 @@ def make_pixel_pipeline(block: int, c0: float = 0.85, c1: float = 0.1,
     def extract(ctx, st):
         v = st.glob["img"][_gid(ctx)]
         return st.set_shared(
-            buf=st.shared["buf"].at[ctx.tid].set(jnp.log(v)))
+            buf=st.shared["buf"].at[ctx.tid].set(logf(v)))
 
     def adjust(ctx, st):
         b = st.shared["buf"]
@@ -432,7 +436,7 @@ def make_pixel_pipeline(block: int, c0: float = 0.85, c1: float = 0.1,
 
     def compress(ctx, st):
         out = st.glob["out"].at[_gid(ctx)].set(
-            jnp.exp(st.shared["buf"][ctx.tid]))
+            expf(st.shared["buf"][ctx.tid]))
         return st.set_glob(out=out)
 
     return KernelDef(
@@ -589,7 +593,7 @@ def make_backprop_layer(in_n: int, out_n: int, lr: float = 0.3) -> KernelDef:
     def store(ctx, st):
         j = ctx.bid
         total = st.shared["s"][0] + st.glob["bias"][j]
-        h = 1.0 / (1.0 + jnp.exp(-total))
+        h = 1.0 / (1.0 + expf(-total))
         idx = jnp.where(ctx.tid == 0, j, OOB)
         hidden = st.glob["hidden"].at[idx].set(h, mode="drop")
         wo = st.glob["w_out"].at[j, ctx.tid].set(
@@ -773,7 +777,7 @@ def make_lavamd(nboxes: int, ppb: int, nnei: int,
         x = st.glob["pos"][ctx.bid * ppb + ctx.tid]
         sy, sq = st.shared["sy"], st.shared["sq"]
         d = x[:, None] - sy[None, :]
-        u = jnp.sum(sq[None, :] * jnp.exp(-alpha * d * d), axis=1)
+        u = jnp.sum(sq[None, :] * expf(-alpha * d * d), axis=1)
         return st.with_priv({"acc": st.priv["acc"] + u})
 
     def store(ctx, st):
@@ -1107,7 +1111,7 @@ class SuiteEntry:
 
 def run_entry(entry: SuiteEntry, backend: str = "loop", *, rng=None,
               args: dict | None = None, grain=1, devices=None, pool=None,
-              interpret: bool = True, grid=None, block=None,
+              interpret: bool | None = None, grid=None, block=None,
               with_reference: bool = True, chain_mode: str = "host",
               chain_stats: ChainStats | None = None,
               check_every: int | None = None,

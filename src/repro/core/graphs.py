@@ -69,7 +69,7 @@ class GraphNode:
     backend: str = "vector"
     grain: int = 1
     dyn_shared: int | None = None
-    interpret: bool = True
+    interpret: bool | None = None
     devices: int | None = None
     shard_axis: str = "blocks"
     reads: tuple[str, ...] = ()
@@ -134,7 +134,7 @@ class Graph:
 
     def add_kernel(self, stream, kernel: KernelDef, *, grid, block,
                    backend: str = "vector", grain=1,
-                   dyn_shared: int | None = None, interpret: bool = True,
+                   dyn_shared: int | None = None, interpret: bool | None = None,
                    pool: int | None = None, devices: int | None = None,
                    shard_axis: str = "blocks",
                    optimize: bool | None = None) -> GraphNode:

@@ -33,11 +33,28 @@ from typing import Any, Callable, Mapping, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import memory
 from repro.core.dim3 import Dim3
 
 WARP_SIZE = 32
+
+
+def expf(x):
+    """CUDA ``expf``, accurate to an ulp or two.
+
+    XLA's default TPU ``exp`` and ``log`` are fast approximations
+    (measured on a TPU v5e over [-1, 1] and [0.5, 2]: ``exp`` 58 ulp,
+    ``log`` about 4000 ulp near 1); the HIGHEST accuracy mode measured
+    1.1 and 2.5 ulp.  Other backends give the same bits either way.
+    """
+    return lax.exp(x, accuracy=lax.AccuracyMode.HIGHEST)
+
+
+def logf(x):
+    """CUDA ``logf``; see :func:`expf`."""
+    return lax.log(x, accuracy=lax.AccuracyMode.HIGHEST)
 
 
 class UnsupportedKernel(Exception):

@@ -1,24 +1,27 @@
 """Emit a CuPBoP-JAX kernel as a ``pl.pallas_call`` (TPU target).
 
-Mapping (DESIGN.md S2):
+Mapping:
 
 * CUDA block           -> one iteration of the grain loop inside a grid step;
 * task-queue fetch     -> one Pallas grid step (grid = ceil(nBlocks/grain));
 * thread axis          -> VPU lanes (vector lowering semantics);
-* __shared__ memory    -> VMEM-resident arrays (functional values; Mosaic
-                          allocates them in VMEM);
-* global memory        -> whole-array VMEM refs ("gather mode" - suits the
-                          irregular demo kernels; the structured hot-path
-                          kernels under ``repro/kernels`` use hand-written
-                          BlockSpec windows instead);
-* written buffers      -> outputs; grid steps on a TensorCore are sequential,
-                          so cross-block accumulation into the output ref is
-                          the TPU-legal atomicAdd adaptation.
+* __shared__ memory    -> functional values inside the kernel body;
+* global memory        -> whole-array refs ("gather mode": every buffer is
+                          one full-array BlockSpec, and the kernel gathers
+                          and scatters inside it);
+* written buffers      -> outputs; grid steps run in order, so
+                          cross-block accumulation into the output ref is
+                          the atomicAdd adaptation.
 
-Validated with ``interpret=True`` on CPU; on a real TPU the same emission
-compiles via Mosaic (grid steps pipeline over cores with
-``dimension_semantics=('arbitrary',)`` because blocks may collide on output
-ranges, exactly like the paper's mutex-guarded queue serializes fetches).
+``interpret=None`` (the default everywhere) resolves from the platform:
+the Pallas interpreter off the TPU, a real Mosaic compile on it.  Mosaic
+accepts none of the suite kernels in gather mode today: the in-kernel
+gathers/scatters of 1-D buffers are refused ("Only 2D gather is
+supported"), as are ``dynamic_slice`` and several 2-D index patterns.  The
+launch path (:func:`repro.core.api._compile`) compiles non-interpret
+entries ahead of time and reports such refusals as
+:class:`~repro.core.kernel.UnsupportedKernel`, so coverage probes show the
+chip's real row.  On the TPU the ``vector`` lowering is the main path.
 """
 from __future__ import annotations
 
@@ -31,8 +34,15 @@ from repro.core.dim3 import Dim3
 from repro.core.kernel import BlockState, Ctx, KernelDef
 
 
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` means: interpret everywhere but on a TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
 def run(kernel: KernelDef, *, grid, block, glob, grain=1, dyn_shared=None,
-        interpret=True):
+        interpret: bool | None = None):
     grid, block = Dim3.of(grid), Dim3.of(block)
     n_blocks, block_size = grid.size, block.size
     names = sorted(glob.keys())
@@ -88,7 +98,7 @@ def run(kernel: KernelDef, *, grid, block, glob, grain=1, dyn_shared=None,
         in_specs=[full_spec(glob[n]) for n in read_only + written],
         out_specs=[full_spec(glob[n]) for n in written],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     outs = call(*[glob[n] for n in read_only + written])
     new_glob = dict(glob)

@@ -354,7 +354,7 @@ class Stream:
                backend: str = "vector", grain: int | str = 1,
                dyn_shared: int | None = None,
                args: dict[str, Any] | None = None,
-               interpret: bool = True, pool: int | None = None,
+               interpret: bool | None = None, pool: int | None = None,
                devices: int | None = None, shard_axis: str = "blocks",
                optimize: bool | None = None):
         """Async launch over the stream's heap.

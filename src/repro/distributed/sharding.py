@@ -21,8 +21,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 # logical axis -> ordered mesh-axis candidates (prefix-greedy)
 RULES: dict[str, tuple[str, ...]] = {
     "batch": ("pod", "data"),
@@ -56,7 +54,7 @@ def use_mesh(mesh: Optional[Mesh], rules: Optional[dict] = None):
         RULES = {**RULES, **rules}
     try:
         if mesh is not None:
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 yield mesh
         else:
             yield None
@@ -68,7 +66,7 @@ def use_mesh(mesh: Optional[Mesh], rules: Optional[dict] = None):
 def make_mesh(shape, axes) -> Mesh:
     return jax.make_mesh(
         tuple(shape), tuple(axes),
-        **compat.mesh_axis_types_kwargs(len(axes)),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 

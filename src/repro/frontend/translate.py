@@ -1,7 +1,8 @@
 """AST -> ``KernelDef`` translator: the heart of the CUDA-C frontend.
 
 The translator emits *Python source* for each barrier-separated stage and
-``exec``s it against a tiny namespace (``jnp`` + the carry helper), so a
+``exec``s it against a tiny namespace (``jnp``, the carry helper and
+CUDA-accurate ``expf``/``logf``), so a
 translated kernel is structurally indistinguishable from a hand-written
 one: same ``(ctx, st) -> st`` stage signature, same thread-chunk
 polymorphism, same fingerprint-hash behavior (all constants are inlined
@@ -30,7 +31,7 @@ import dataclasses
 
 import jax.numpy as jnp
 
-from repro.core.kernel import KernelDef, UnsupportedKernel
+from repro.core.kernel import KernelDef, UnsupportedKernel, expf, logf
 from repro.frontend import parser as P
 from repro.frontend.lexer import macro_names
 from repro.frontend.runtime import carry
@@ -51,8 +52,8 @@ _MATH = {
     "fmin": ("jnp.minimum", "float"), "fmax": ("jnp.maximum", "float"),
     "abs": ("jnp.abs", None), "fabs": ("jnp.abs", "float"),
     "fabsf": ("jnp.abs", "float"),
-    "expf": ("jnp.exp", "float"), "exp": ("jnp.exp", "float"),
-    "logf": ("jnp.log", "float"), "log": ("jnp.log", "float"),
+    "expf": ("expf", "float"), "exp": ("expf", "float"),
+    "logf": ("logf", "float"), "log": ("logf", "float"),
     "sqrtf": ("jnp.sqrt", "float"), "sqrt": ("jnp.sqrt", "float"),
     "powf": ("jnp.power", "float"), "pow": ("jnp.power", "float"),
 }
@@ -67,7 +68,7 @@ _VOTE = {"__ballot_sync": "ctx.ballot", "__all_sync": "ctx.vote_all",
 _ATOMICS = ("atomicAdd", "atomicMax", "atomicMin", "atomicCAS",
             "atomicExch")
 
-_RESERVED = {"ctx", "st", "jnp", "_carry", "range"}
+_RESERVED = {"ctx", "st", "jnp", "_carry", "range", "expf", "logf"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -729,7 +730,7 @@ def translate(src: str, *, bind: dict | None = None,
     tr = _Translator(kast, unit.constants, scalar_bind)
     sources, meta = tr.run()
 
-    ns = {"jnp": jnp, "_carry": carry}
+    ns = {"jnp": jnp, "_carry": carry, "expf": expf, "logf": logf}
     stage_fns = []
     for i, stage_src in enumerate(sources):
         code = compile(stage_src, f"<cuda:{kast.name}:stage{i}>", "exec")

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.core.pallas_emit import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +82,7 @@ def _kernel(qi_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "q_blk", "kv_blk",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal=True, q_blk=128, kv_blk=128,
-                    interpret=True):
+                    interpret=None):
     """q: [B, H, Sq, d]; k/v: [B, Hkv, Skv, d] with H % Hkv == 0."""
     B, H, Sq, d = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]
@@ -114,8 +114,8 @@ def flash_attention(q, k, v, *, causal=True, q_blk=128, kv_blk=128,
             pltpu.VMEM((q_blk,), jnp.float32),         # running denom
             pltpu.VMEM((q_blk, d), jnp.float32),       # accumulator
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.core.pallas_emit import resolve_interpret
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk):
@@ -36,7 +36,7 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "grain",
                                              "interpret"))
-def matmul(a, b, *, bm=128, bn=128, bk=128, grain=1, interpret=True):
+def matmul(a, b, *, bm=128, bn=128, bk=128, grain=1, interpret=None):
     """a: [M, K] @ b: [K, N] -> [M, N]."""
     M, K = a.shape
     K2, N = b.shape
@@ -55,7 +55,7 @@ def matmul(a, b, *, bm=128, bn=128, bk=128, grain=1, interpret=True):
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.pallas_emit import resolve_interpret
+
 
 def _kernel(x_ref, s_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)                  # [grain, D]
@@ -23,7 +25,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps):
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "grain", "interpret"))
-def rmsnorm(x, scale, *, eps=1e-5, grain=8, interpret=True):
+def rmsnorm(x, scale, *, eps=1e-5, grain=8, interpret=None):
     """x: [rows, D]; scale: [D]."""
     rows, D = x.shape
     grain = min(grain, rows)
@@ -38,5 +40,5 @@ def rmsnorm(x, scale, *, eps=1e-5, grain=8, interpret=True):
         ],
         out_specs=pl.BlockSpec((grain, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, D), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, scale)

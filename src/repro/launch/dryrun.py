@@ -28,7 +28,6 @@ import traceback
 
 import jax
 
-from repro import compat
 from repro.configs import registry
 from repro.configs.registry import SHAPES
 from repro.distributed import sharding as shd
@@ -195,7 +194,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         }
         rec["memory"]["fits_16gb_hbm"] = \
             rec["memory"]["peak_per_chip_gb"] <= 16.0
-        xla_cost = compat.xla_cost_analysis(compiled)
+        xla_cost = compiled.cost_analysis()
         rec["xla_flops_once"] = float(xla_cost.get("flops", -1))
 
         hlo = compiled.as_text()

@@ -13,7 +13,8 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+#: every mesh axis is Auto: the models annotate shardings, XLA places
+AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,8 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     need = math.prod(shape)
     devs = jax.devices()
     if len(devs) == need:
-        return jax.make_mesh(
-            shape, axes, **compat.mesh_axis_types_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=(AUTO,) * len(axes))
     if len(devs) < need:
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, have {len(devs)} - the "
@@ -34,11 +34,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     # more devices than needed (e.g. 512 host devices, single-pod 256 mesh)
     return jax.sharding.Mesh(
         np.asarray(devs[:need]).reshape(shape), axes,
-        **compat.mesh_axis_types_kwargs(len(axes)))
+        axis_types=(AUTO,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU multi-device tests (subprocess sets device count)."""
-    return jax.make_mesh(
-        tuple(shape), tuple(axes),
-        **compat.mesh_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AUTO,) * len(axes))

@@ -105,7 +105,7 @@ def main(argv=None):
     ap.add_argument("--requests", type=int, default=None,
                     help="request count (default: 48 kernel / 8 lm)")
     # kernel-service mode
-    ap.add_argument("--backend", default="loop")
+    ap.add_argument("--backend", default="vector")
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--window-ms", type=float, default=2.0)
     ap.add_argument("--timeout", type=float, default=120.0)
@@ -127,4 +127,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.core import compile_cache
+    compile_cache.use_jax_cache()
     main()

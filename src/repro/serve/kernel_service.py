@@ -35,7 +35,10 @@ Failure isolation: any per-request error (``SanitizerError``,
 ``OptimizeError``, ``CudaError``, ``UnsupportedSpace``, ...) is caught
 and stored on that request's ticket; a failing *stacked* dispatch falls
 back to independent dispatches so one poisoned tenant cannot take down
-co-batched requests; the worker thread never dies with the service open.
+co-batched requests, and is counted (``batch_fallbacks``, with the last
+error's text) so a batch that fails on the device for every tenant shows
+up as a fault, not as low occupancy; the worker thread never dies with the
+service open.
 
 Observability: :meth:`KernelService.stats` snapshots a
 :class:`ServiceStats` - per-kernel p50/p99 latency, throughput, warm-hit
@@ -174,6 +177,8 @@ class ServiceStats:
     every dispatch since the service started - per *dispatch*, not per
     request: a warm batch of 8 requests is one hit.  ``batch_occupancy``
     maps actual batch size -> number of dispatches at that size.
+    ``batch_fallbacks`` counts stacked dispatches that raised and were
+    re-run as singles; ``last_batch_error`` is the latest one's text.
     """
 
     submitted: int
@@ -191,6 +196,8 @@ class ServiceStats:
     cache_hits: int
     cache_misses: int
     batch_occupancy: dict
+    batch_fallbacks: int
+    last_batch_error: str | None
     kernels: dict
     streams: dict
 
@@ -222,7 +229,7 @@ class KernelService:
     work and stops the worker.
     """
 
-    def __init__(self, *, backend: str = "loop",
+    def __init__(self, *, backend: str = "vector",
                  policy: Policy = Policy.HAZARD_ONLY,
                  max_queue: int = 256, max_batch: int = 16,
                  admission_window_ms: float = 2.0,
@@ -254,6 +261,8 @@ class KernelService:
         self._timed_out = self._rejected = 0
         self._dispatches = self._batched_requests = 0
         self._max_depth = 0
+        self._batch_fallbacks = 0
+        self._last_batch_error: str | None = None
         self._occupancy: collections.Counter = collections.Counter()
         self._latency: dict[str, collections.deque] = {}
         if autostart:
@@ -431,16 +440,17 @@ class KernelService:
         if len(batch) > 1:
             try:
                 outs = self._run_batch(ep, batch)
-            except UnsupportedKernel:
-                # the backend genuinely cannot stack this specialization -
-                # remember, so later traffic skips straight to singles
-                with self._lock:
-                    self._unbatchable.add(batch[0].key)
-            except Exception:
+            except Exception as e:      # noqa: BLE001 - isolation boundary
                 # a poisoned tenant (bad binding, sanitizer finding, ...)
                 # failed the stacked dispatch as a unit: fall through to
                 # independent dispatches so it only takes itself down
-                pass
+                with self._lock:
+                    self._batch_fallbacks += 1
+                    self._last_batch_error = f"{type(e).__name__}: {e}"
+                    if isinstance(e, UnsupportedKernel):
+                        # the backend cannot stack this specialization:
+                        # later traffic skips straight to singles
+                        self._unbatchable.add(batch[0].key)
             else:
                 self._record_dispatch(len(batch), batched=True)
                 for t, out in zip(batch, outs):
@@ -573,6 +583,8 @@ class KernelService:
                 warm_hit_rate=round(hits / max(hits + misses, 1), 4),
                 cache_hits=hits, cache_misses=misses,
                 batch_occupancy=dict(self._occupancy),
+                batch_fallbacks=self._batch_fallbacks,
+                last_batch_error=self._last_batch_error,
                 kernels=kernels,
                 streams={
                     "launches": self.runtime.stats.launches,

@@ -1,6 +1,7 @@
 """CUDA-faithful API surface: dim3, triple-chevron, registry, streams+events."""
 import gc
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -206,6 +207,23 @@ def test_cache_keyed_on_kernel_object_not_id():
     assert api.cache_size() == 1
     cache_clear()
     assert api.cache_size() == 0
+
+
+def test_interpret_default_resolves_from_platform():
+    """interpret=None means "interpret off the TPU"; the launch cache keys
+    the resolved value, so the default and an explicit True share one
+    specialization on the CPU."""
+    n = 256
+    k = make_vecadd(n)
+    args = {"a": jnp.ones(n), "b": jnp.ones(n), "c": jnp.zeros(n)}
+    default = api.compiled(k, grid=2, block=128, args=args,
+                           backend="pallas")
+    assert api.compiled(k, grid=2, block=128, args=args, backend="pallas",
+                        interpret=True) is default
+    assert jax.default_backend() != "tpu"
+    out = launch(k, grid=2, block=128, args=args, backend="pallas")
+    np.testing.assert_allclose(np.asarray(out["c"]), 2.0)
+    assert api.cache_size() >= 1
 
 
 # --- streams, events, hazards ------------------------------------------------

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.configs import registry
 from repro.launch import hlo_analysis as H
 
@@ -24,7 +23,7 @@ def test_hlo_analysis_matches_xla_loop_free():
             jax.ShapeDtypeStruct((512, 64), jnp.float32))
     c = jax.jit(f).lower(*args).compile()
     ours = H.analyze(c.as_text())
-    xla = compat.xla_cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(ours.flops - xla) / xla < 0.05
 
 
@@ -41,7 +40,7 @@ def test_hlo_analysis_scan_trip_count():
     expect = 2 * 128 * 256 * 256 * 10
     assert abs(ours.flops - expect) / expect < 0.05
     # XLA itself undercounts by ~the trip count (the reason this module exists)
-    assert compat.xla_cost_analysis(c)["flops"] < expect / 5
+    assert c.cost_analysis()["flops"] < expect / 5
 
 
 def test_input_specs_shapes():
@@ -135,7 +134,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, jax, jax.numpy as jnp, numpy as np
 from repro.checkpoint.ckpt import CheckpointManager
-from repro import compat
 from repro.configs import registry
 from repro.distributed import sharding as shd
 from repro.models import transformer as T

@@ -1,6 +1,8 @@
 """Graph capture/instantiate/replay + compile-cache counters (ISSUE 2)."""
 import gc
+import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,6 +12,7 @@ from repro.core import (
     Runtime,
     Stream,
     api,
+    compile_cache,
     launch,
 )
 from repro.core.cuda_suite import (
@@ -337,6 +340,32 @@ def test_disk_cache_roundtrip(tmp_path):
     finally:
         api.disable_disk_cache()
         api.cache_clear()
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "repo"])
+def test_jax_cache_dir_env_wins_else_fixed_repo_path(env_dir, tmp_path,
+                                                     monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; without it the cache goes
+    to <repo>/.jax_cache, a path built from nothing that changes per run."""
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        path = compile_cache.use_jax_cache()
+        now = jax.config.jax_compilation_cache_dir
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          was[1])
+    if env_dir:
+        assert path == str(tmp_path) and now == was[0]
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == now == os.path.join(repo, ".jax_cache")
 
 
 def test_compiled_preresolves_without_running():

@@ -240,6 +240,40 @@ def test_tenant_fault_isolated_from_cobatched_and_subsequent(kernel):
         svc.close()
 
 
+def test_batch_fallback_to_singles_is_counted(kernel):
+    """A stacked dispatch that fails and re-runs as singles is a visible
+    fault: counted, with its error text, while its co-batched tenants
+    still get correct results."""
+    rng = np.random.default_rng(11)
+    svc = KernelService(backend="loop", autostart=False, max_batch=8)
+    try:
+        svc.register("vecadd", kernel, grid=GRID, block=BLOCK)
+        good_args = [vecadd_args(rng) for _ in range(3)]
+        bad_args = vecadd_args(rng)
+        bad_args["c"] = memory.ConstArray(jnp.zeros(N, jnp.float32))
+        goods = [svc.submit("vecadd", a) for a in good_args]
+        bad = svc.submit("vecadd", bad_args)
+        svc.start()
+        with pytest.raises(memory.UnsupportedSpace):
+            bad.result(timeout=120)
+        for t, a in zip(goods, good_args):
+            want = api.launch(kernel, grid=GRID, block=BLOCK, args=a,
+                              backend="loop")
+            assert _bits(t.result(timeout=120)["c"]) == _bits(want["c"])
+            assert t.batch_size == 1            # served by the fallback
+        st = svc.stats()
+        assert st.batch_fallbacks == 1
+        assert st.last_batch_error.startswith("UnsupportedSpace")
+        assert st.to_json()["batch_fallbacks"] == 1
+        # a clean stacked dispatch afterwards adds no fallback
+        clean = [svc.submit("vecadd", vecadd_args(rng)) for _ in range(2)]
+        for t in clean:
+            t.result(timeout=120)
+        assert svc.stats().batch_fallbacks == 1
+    finally:
+        svc.close()
+
+
 def test_freed_handle_rejected_at_admission(kernel):
     rng = np.random.default_rng(10)
     svc = KernelService(backend="loop", autostart=False)
