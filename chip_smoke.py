@@ -26,8 +26,11 @@ each bit-identical to ``vector`` on one chip.
     python3 chip_smoke.py --four-chips  # a four-chip host
 
 Times printed along the way are one-off bring-up readings, not benchmark
-metrics.  The last line of standard output is one JSON object naming the
-device; the exit code is non-zero on any failure.
+metrics; ``trace`` and ``first_call`` are the program's own compile-layer
+counters (``api.cache_stats()``: ``trace_s`` and ``first_call_s``, graph
+replays included), and the closing line counts JAX persistent-cache hits.
+The last line of standard output is one JSON object naming the device;
+the exit code is non-zero on any failure.
 """
 from __future__ import annotations
 
@@ -46,7 +49,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import UnsupportedKernel, compile_cache  # noqa: E402
+from repro.core import UnsupportedKernel, api, compile_cache  # noqa: E402
 from repro.core.conformance import oracle_check  # noqa: E402
 from repro.core.cuda_suite import (build_suite, entry_hotspot,  # noqa: E402
                                    entry_lavamd, run_entry)
@@ -63,28 +66,28 @@ class SmokeFailure(RuntimeError):
     """A phase found a wrong answer or a missing mechanism."""
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling (or fetching a
-    compiled program from its persistent cache), read from JAX's own
-    monitoring events."""
+def compile_clock() -> tuple[float, float]:
+    """Host seconds so far in the launch cache's miss path and in first
+    dispatches of new specializations."""
+    s = api.cache_stats()
+    return s.trace_s, s.first_call_s
 
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
 
-    def __init__(self):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
+def count_cache_hits() -> list[int]:
+    """A one-element counter of JAX persistent-cache hits from now on."""
+    hits = [0]
 
-    def _duration(self, event, secs, **_):
-        if event in self.EVENTS:
-            self.seconds += secs
-
-    def _event(self, event, **_):
+    def on_event(event: str, **_) -> None:
         if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+            hits[0] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    return hits
+
+
+def compile_since(c0: tuple[float, float]) -> str:
+    trace, first = (b - a for a, b in zip(c0, compile_clock()))
+    return f"trace={trace:.4f}s first_call={first:.4f}s"
 
 
 def check(out, want, tol, what):
@@ -98,11 +101,11 @@ def bits(a) -> bytes:
     return np.asarray(a).tobytes()
 
 
-def timed(fn, clock):
-    c0, t0 = clock.seconds, time.perf_counter()
+def timed(fn):
+    c0, t0 = compile_clock(), time.perf_counter()
     out = fn()
     jax.block_until_ready(out)
-    return out, time.perf_counter() - t0, clock.seconds - c0
+    return out, time.perf_counter() - t0, compile_since(c0)
 
 
 def phase_suite(scale: int = 1) -> None:
@@ -137,7 +140,7 @@ def phase_suite(scale: int = 1) -> None:
                            + " | ".join(failures))
 
 
-def phase_hotspot(h: int, w: int, iters: int, clock: CompileClock) -> None:
+def phase_hotspot(h: int, w: int, iters: int) -> None:
     """hotspot at deployment size through the three chain replay modes."""
     e = entry_hotspot(h, w, iters)
     t0 = time.perf_counter()
@@ -150,18 +153,18 @@ def phase_hotspot(h: int, w: int, iters: int, clock: CompileClock) -> None:
         def go(mode=mode):
             return run_entry(e, "vector", args=args, chain_mode=mode,
                              with_reference=False)[0]
-        _, cold_s, compile_s = timed(go, clock)
-        out, warm_s, _ = timed(go, clock)
+        _, cold_s, compile_s = timed(go)
+        out, warm_s, _ = timed(go)
         err = check(out, want, e.tol, f"hotspot {mode}")
         t_out[mode] = bits(out["t_out"])
         print(f"hotspot mode={mode:6s} wall={warm_s:.4f}s "
-              f"first_run={cold_s:.4f}s compile={compile_s:.4f}s "
+              f"first_run={cold_s:.4f}s {compile_s} "
               f"max_err={err:.3g}", flush=True)
     if len(set(t_out.values())) != 1:
         raise SmokeFailure("hotspot: host/device/graph t_out bits differ")
 
 
-def phase_serving(scale: int, clock: CompileClock) -> None:
+def phase_serving(scale: int) -> None:
     """Two waves of concurrent requests through a vector KernelService."""
     entries = {e.name: e for e in build_suite(scale)
                if e.name in SERVE_ROSTER}
@@ -197,13 +200,13 @@ def phase_serving(scale: int, clock: CompileClock) -> None:
                     t.start()
                 for t in threads:
                     t.join()
-            t0, c0 = time.perf_counter(), clock.seconds
+            t0, c0 = time.perf_counter(), compile_clock()
             for (e, a), t in zip(reqs, tickets):
                 check(t.result(timeout=600.0), e.reference(a), e.tol,
                       f"serving {e.name} request {t.rid}")
             print(f"serving wave={wave} requests={len(reqs)} "
                   f"wall={time.perf_counter() - t0:.4f}s "
-                  f"compile={clock.seconds - c0:.4f}s", flush=True)
+                  f"{compile_since(c0)}", flush=True)
         st = svc.stats()
     finally:
         svc.close()
@@ -264,14 +267,14 @@ def main(argv=None) -> int:
         return 1
     print(f"jax compilation cache: {compile_cache.use_jax_cache()}",
           flush=True)
-    clock = CompileClock()
+    cache_hits = count_cache_hits()
     phases = ([("four_chips", phase_four_chips)] if args.four_chips else
               [("suite", phase_suite),
-               ("hotspot", lambda: phase_hotspot(*HOTSPOT, clock)),
-               ("serving", lambda: phase_serving(SERVE_SCALE, clock))])
+               ("hotspot", lambda: phase_hotspot(*HOTSPOT)),
+               ("serving", lambda: phase_serving(SERVE_SCALE))])
     t_start = time.perf_counter()
     for name, fn in phases:
-        t0, c0 = time.perf_counter(), clock.seconds
+        t0, c0 = time.perf_counter(), compile_clock()
         try:
             fn()
         except Exception as e:  # noqa: BLE001 - any failure fails the run
@@ -280,10 +283,10 @@ def main(argv=None) -> int:
                   file=sys.stderr, flush=True)
             return 1
         print(f"phase {name} ok: {time.perf_counter() - t0:.2f}s, "
-              f"compile {clock.seconds - c0:.2f}s", flush=True)
-    print(f"total {time.perf_counter() - t_start:.2f}s, compile "
-          f"{clock.seconds:.2f}s, persistent-cache hits {clock.cache_hits}",
-          flush=True)
+              f"{compile_since(c0)}", flush=True)
+    print(f"total {time.perf_counter() - t_start:.2f}s, "
+          f"{compile_since((0.0, 0.0))}, persistent-cache hits "
+          f"{cache_hits[0]}", flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

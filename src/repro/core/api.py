@@ -23,12 +23,24 @@ is two-level: a bounded in-memory LRU of :class:`CompiledKernel` entries
 (warm launches skip trace+lower entirely) over an optional on-disk artifact
 store (:mod:`repro.core.compile_cache` - the ``cudaModuleLoad`` analogue,
 enabled via ``CUPBOP_CACHE_DIR`` or :func:`enable_disk_cache`).
+
+Tracing: each launch is a ``cupbop.launch`` span (``cupbop.launch_batch``
+for stacked batches) holding ``cupbop.compile`` around the miss path and
+``cupbop.dispatch`` around the call of the compiled entry.  The spans are
+``jax.profiler.TraceAnnotation``s, recorded only while a profiler session
+is on, on the same clock as the device trace.  Every device program is
+named for its kernel (XLA module ``jit_<kernel>__<backend>``), and the
+kernel body runs under ``jax.named_scope(<kernel>)``.  :class:`CacheStats`
+times the miss path, first dispatches and warm launches at all times.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import os
+import re
+import time
 import traceback
 import weakref
 from typing import Any
@@ -77,6 +89,16 @@ class CacheStats:
     served by deserializing an on-disk artifact instead of re-tracing;
     ``disk_stores`` count artifacts persisted; ``evictions`` count LRU
     drops after the cache exceeded its bound.
+
+    Host seconds, from ``time.perf_counter``: ``trace_s`` is the miss path
+    (the ``eval_shape`` trace, the Mosaic compile for ``pallas``, any
+    disk-artifact load or store); ``first_calls``/``first_call_s`` count
+    and time the first dispatch of each new specialization, a graph
+    replay's included (XLA lowering plus the backend compile or the
+    persistent-cache fetch); ``warm_launches``/``warm_launch_s`` count and
+    time whole launches of a specialization already dispatched before -
+    the steady-state launch cost, sanitize and optimize passes included
+    when they are on.
     """
 
     hits: int = 0
@@ -84,9 +106,15 @@ class CacheStats:
     evictions: int = 0
     disk_hits: int = 0
     disk_stores: int = 0
+    trace_s: float = 0.0
+    first_calls: int = 0
+    first_call_s: float = 0.0
+    warm_launches: int = 0
+    warm_launch_s: float = 0.0
 
 
 _STATS = CacheStats()
+_span = jax.profiler.TraceAnnotation
 
 
 def __getattr__(name: str):
@@ -174,18 +202,36 @@ def device_opts(backend_entry, devices, shard_axis) -> dict:
     return {}
 
 
-def _build(kernel: KernelDef, backend: str, grid: Dim3, block: Dim3,
-           grain: int, dyn_shared, treedef, interpret: bool,
-           devices, shard_axis, donate_idx: tuple[int, ...] = ()):
+def program_name(*parts: str) -> str:
+    """``parts`` joined by ``__`` with every character outside
+    ``[A-Za-z0-9_]`` replaced by ``_``: the name a device program carries
+    (its XLA module reads ``jit_<name>``)."""
+    return re.sub(r"[^A-Za-z0-9_]", "_", "__".join(parts))
+
+
+def _body(kernel: KernelDef, backend: str, grid: Dim3, block: Dim3,
+          grain: int, dyn_shared, treedef, interpret: bool, **extra):
+    """The kernel over packed leaves, named ``<kernel>__<backend>``."""
     entry = get_backend(backend)
-    extra = device_opts(entry, devices, shard_axis)
+    scope = program_name(kernel.name)
 
     def fn(*leaves):
         glob = packing.unpack(leaves, treedef)  # kernel prologue (SIII-C.2)
-        return entry.run(kernel, grid=grid, block=block, glob=glob,
-                         grain=grain, dyn_shared=dyn_shared,
-                         interpret=interpret, **extra)
+        with jax.named_scope(scope):
+            return entry.run(kernel, grid=grid, block=block, glob=glob,
+                             grain=grain, dyn_shared=dyn_shared,
+                             interpret=interpret, **extra)
 
+    fn.__name__ = fn.__qualname__ = program_name(kernel.name, backend)
+    return fn
+
+
+def _build(kernel: KernelDef, backend: str, grid: Dim3, block: Dim3,
+           grain: int, dyn_shared, treedef, interpret: bool,
+           devices, shard_axis, donate_idx: tuple[int, ...] = ()):
+    fn = _body(kernel, backend, grid, block, grain, dyn_shared, treedef,
+               interpret, **device_opts(get_backend(backend), devices,
+                                        shard_axis))
     # leaves of declared-donated, handle-bound buffers hand their storage
     # to XLA: the input array is consumed (deleted) and may alias the
     # output buffer - safe because the caller's only path to it is the
@@ -223,8 +269,13 @@ def _compile(kernel: KernelDef, backend: str, grid: Dim3, block: Dim3,
             # does not carry aliasing); handle re-binding still applies, so
             # semantics match - only the storage reuse is lost
             _STATS.disk_hits += 1
+
+            def fn(*leaves):
+                return loaded(*leaves)
+
+            fn.__name__ = fn.__qualname__ = program_name(kernel.name, backend)
             return CompiledKernel(kernel=kernel, backend=backend, grid=grid,
-                                  block=block, key=key, fn=jax.jit(loaded),
+                                  block=block, key=key, fn=jax.jit(fn),
                                   source="disk")
     fn = _build(kernel, backend, grid, block, grain, dyn_shared, treedef,
                 interpret, devices, shard_axis, donate_idx)
@@ -291,12 +342,46 @@ def _entry_for(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
         _lru_touch(kernel, key)
         return entry, leaves
     _STATS.misses += 1
-    entry = _compile(kernel, backend, grid, block, grain, dyn_shared,
-                     interpret, treedef, leaves, shapes, key, devices,
-                     shard_axis, donate_idx)
+    with _miss_path():
+        entry = _compile(kernel, backend, grid, block, grain, dyn_shared,
+                         interpret, treedef, leaves, shapes, key, devices,
+                         shard_axis, donate_idx)
     per_kernel[key] = entry
     _lru_insert(kernel, key)
     return entry, leaves
+
+
+@contextlib.contextmanager
+def _miss_path():
+    """The ``cupbop.compile`` span; its host seconds go to
+    :attr:`CacheStats.trace_s`."""
+    t0 = time.perf_counter()
+    try:
+        with _span("cupbop.compile"):
+            yield
+    finally:
+        _STATS.trace_s += time.perf_counter() - t0
+
+
+def count_first_dispatch(t0: float) -> None:
+    """Count the first dispatch of a new specialization (a launch entry,
+    or a graph replay), begun at ``time.perf_counter()`` ``t0``, in
+    :class:`CacheStats` ``first_calls``/``first_call_s``."""
+    _STATS.first_calls += 1
+    _STATS.first_call_s += time.perf_counter() - t0
+
+
+def _dispatch(entry: CompiledKernel, leaves) -> tuple[Any, bool]:
+    """Call a compiled entry under ``cupbop.dispatch``; the first call of
+    a specialization is counted with :func:`count_first_dispatch`.
+    Returns the outputs and whether this was that first call."""
+    first = entry.hits == 0
+    t0 = time.perf_counter()
+    with _span("cupbop.dispatch"):
+        out = entry(*leaves)
+    if first:
+        count_first_dispatch(t0)
+    return out, first
 
 
 def _donate_leaf_indices(resolved_args: dict, donated: set) -> tuple:
@@ -331,32 +416,39 @@ def _launch(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
             pool, devices=None, shard_axis: str = "blocks",
             sanitize: bool | None = None,
             optimize: bool | None = None) -> dict:
-    if _sanitize_enabled(sanitize):
-        # kernelcheck gate: races / declaration drift / donation hazards
-        # fail the launch before any compiled entry runs.  Clean verdicts
-        # are memoized on the kernel, so chains re-check for free.
-        # Runs on the BASE kernel (before any optimize rewrite) so finding
-        # stage indices match the author's source.
-        from repro.core import analyze as analyze_mod
-        analyze_mod.sanitize_launch(kernel, grid=grid, block=block,
-                                    args=args, dyn_shared=dyn_shared)
-    if _optimize_enabled(optimize):
-        # barrier-fission optimizer: swap in the verdict-backed derived
-        # kernel (memoized per geometry+shapes).  The derived kernel has
-        # its own fingerprint domain, so both compile-cache tiers keep
-        # optimized and unoptimized specializations apart.
-        from repro.core import optimize as optimize_mod
-        kernel = optimize_mod.optimize_launch(kernel, grid=grid,
-                                              block=block, args=args,
-                                              dyn_shared=dyn_shared)
-    entry, leaves = _entry_for(kernel, grid, block, args, backend, grain,
-                               dyn_shared, interpret, pool, devices,
-                               shard_axis)
-    out = entry(*leaves)
-    # donated handle-bound buffers come back as the SAME handle, re-bound
-    # to the kernel's output (the CUDA in-place view); everything else is
-    # a plain functional result
-    return memory_mod.rebind_outputs(kernel, args, out)
+    t0 = time.perf_counter()
+    with _span("cupbop.launch", kernel=kernel.name, backend=backend):
+        if _sanitize_enabled(sanitize):
+            # kernelcheck gate: races / declaration drift / donation
+            # hazards fail the launch before any compiled entry runs.
+            # Clean verdicts are memoized on the kernel, so chains
+            # re-check for free.  Runs on the BASE kernel (before any
+            # optimize rewrite) so finding stage indices match the
+            # author's source.
+            from repro.core import analyze as analyze_mod
+            analyze_mod.sanitize_launch(kernel, grid=grid, block=block,
+                                        args=args, dyn_shared=dyn_shared)
+        if _optimize_enabled(optimize):
+            # barrier-fission optimizer: swap in the verdict-backed
+            # derived kernel (memoized per geometry+shapes).  The derived
+            # kernel has its own fingerprint domain, so both compile-cache
+            # tiers keep optimized and unoptimized specializations apart.
+            from repro.core import optimize as optimize_mod
+            kernel = optimize_mod.optimize_launch(kernel, grid=grid,
+                                                  block=block, args=args,
+                                                  dyn_shared=dyn_shared)
+        entry, leaves = _entry_for(kernel, grid, block, args, backend,
+                                   grain, dyn_shared, interpret, pool,
+                                   devices, shard_axis)
+        out, first = _dispatch(entry, leaves)
+        # donated handle-bound buffers come back as the SAME handle,
+        # re-bound to the kernel's output (the CUDA in-place view);
+        # everything else is a plain functional result
+        out = memory_mod.rebind_outputs(kernel, args, out)
+    if not first:
+        _STATS.warm_launches += 1
+        _STATS.warm_launch_s += time.perf_counter() - t0
+    return out
 
 
 def compiled(kernel: KernelDef, *, grid, block, args: dict,
@@ -493,20 +585,17 @@ def _build_batch(kernel: KernelDef, backend: str, grid: Dim3, block: Dim3,
                  grain: int, dyn_shared, treedef, interpret: bool):
     """Jitted entry running N stacked launches of one specialization.
 
-    The inner fn is the same per-launch builder :func:`_build` jits; here
+    The inner fn is the same per-launch body :func:`_build` jits; here
     it is ``vmap``-ed over a leading request axis instead, so N compatible
-    launches become ONE dispatch.  Stacking and row-indexing are pure data
-    movement and the lowerings are rank-polymorphic jnp programs, so each
-    row is bit-identical to the independent launch it replaces.
+    launches become ONE dispatch (named ``<kernel>__<backend>__batch``).
+    Stacking and row-indexing are pure data movement and the lowerings are
+    rank-polymorphic jnp programs, so each row is bit-identical to the
+    independent launch it replaces.
     """
-    entry = get_backend(backend)
-
-    def one(*leaves):
-        glob = packing.unpack(leaves, treedef)
-        return entry.run(kernel, grid=grid, block=block, glob=glob,
-                         grain=grain, dyn_shared=dyn_shared,
-                         interpret=interpret)
-
+    one = _body(kernel, backend, grid, block, grain, dyn_shared, treedef,
+                interpret)
+    one.__name__ = one.__qualname__ = program_name(kernel.name, backend,
+                                                   "batch")
     return jax.jit(jax.vmap(one))
 
 
@@ -539,67 +628,72 @@ def launch_batch(kernel: KernelDef, *, grid, block, args_list: list[dict],
     if not args_list:
         raise ValueError("launch_batch: args_list must be non-empty")
     grid, block = Dim3.of(grid), Dim3.of(block)
-    if _sanitize_enabled(sanitize):
-        from repro.core import analyze as analyze_mod
-        analyze_mod.sanitize_launch(kernel, grid=grid, block=block,
-                                    args=args_list[0], dyn_shared=dyn_shared)
-    if _optimize_enabled(optimize):
-        from repro.core import optimize as optimize_mod
-        kernel = optimize_mod.optimize_launch(kernel, grid=grid, block=block,
-                                              args=args_list[0],
-                                              dyn_shared=dyn_shared)
-    if len(args_list) == 1:
-        # a batch of one is a plain launch (donation and disk tier apply);
-        # passes run above, so suppress the env-var defaults here
-        return [_launch(kernel, grid, block, args_list[0], backend, grain,
-                        dyn_shared, interpret, pool,
-                        sanitize=False, optimize=False)]
-    if get_backend(backend).supports("multi_device"):
-        raise UnsupportedKernel(
-            f"launch_batch: backend {backend!r} shards blocks across "
-            f"devices; stacked request batching is single-device only - "
-            f"dispatch these requests independently")
-    grain = _resolve_grain(kernel, grain, pool, grid.size)
-    interpret = pallas_emit.resolve_interpret(interpret)
-    packed, treedef0, shapes0 = [], None, None
-    for i, a in enumerate(args_list):
-        leaves, treedef = packing.pack(
-            memory_mod.resolve_launch_args(kernel, a))
-        shapes = tuple((l.shape, jnp.asarray(l).dtype.name) for l in leaves)
-        if i == 0:
-            treedef0, shapes0 = treedef, shapes
-        elif (treedef, shapes) != (treedef0, shapes0):
-            raise ValueError(
-                f"launch_batch: request {i} does not match the batch "
-                f"specialization (buffer structure or leaf shapes/dtypes "
-                f"differ from request 0); only compatible launches stack")
-        packed.append(leaves)
-    n = len(packed)
-    stacked = tuple(jnp.stack([p[j] for p in packed])
-                    for j in range(len(packed[0])))
-    key = ("batch", n, backend, grid, block, grain, dyn_shared, interpret,
-           treedef0, shapes0)
-    per_kernel = _kernel_cache(kernel)
-    entry = per_kernel.get(key)
-    if entry is not None:
-        _STATS.hits += 1
-        _lru_touch(kernel, key)
-    else:
-        _STATS.misses += 1
-        fn = _build_batch(kernel, backend, grid, block, grain, dyn_shared,
-                          treedef0, interpret)
-        # surface UnsupportedKernel eagerly, as the single-launch path does
-        jax.eval_shape(fn, *stacked)
-        if backend == "pallas" and not interpret:
-            mosaic_compile(fn, stacked)
-        entry = CompiledKernel(kernel=kernel, backend=backend, grid=grid,
-                               block=block, key=key, fn=fn, source="trace")
-        per_kernel[key] = entry
-        _lru_insert(kernel, key)
-    out = entry(*stacked)
-    return [memory_mod.rebind_outputs(
-                kernel, a, {name: v[i] for name, v in out.items()})
-            for i, a in enumerate(args_list)]
+    with _span("cupbop.launch_batch", kernel=kernel.name, n=len(args_list)):
+        if _sanitize_enabled(sanitize):
+            from repro.core import analyze as analyze_mod
+            analyze_mod.sanitize_launch(kernel, grid=grid, block=block,
+                                        args=args_list[0],
+                                        dyn_shared=dyn_shared)
+        if _optimize_enabled(optimize):
+            from repro.core import optimize as optimize_mod
+            kernel = optimize_mod.optimize_launch(kernel, grid=grid,
+                                                  block=block,
+                                                  args=args_list[0],
+                                                  dyn_shared=dyn_shared)
+        if len(args_list) == 1:
+            # a batch of one is a plain launch (donation and disk tier
+            # apply); passes run above, so suppress the env-var defaults
+            return [_launch(kernel, grid, block, args_list[0], backend, grain,
+                            dyn_shared, interpret, pool,
+                            sanitize=False, optimize=False)]
+        if get_backend(backend).supports("multi_device"):
+            raise UnsupportedKernel(
+                f"launch_batch: backend {backend!r} shards blocks across "
+                f"devices; stacked request batching is single-device "
+                f"only - dispatch these requests independently")
+        grain = _resolve_grain(kernel, grain, pool, grid.size)
+        interpret = pallas_emit.resolve_interpret(interpret)
+        packed, treedef0, shapes0 = [], None, None
+        for i, a in enumerate(args_list):
+            leaves, treedef = packing.pack(
+                memory_mod.resolve_launch_args(kernel, a))
+            shapes = tuple((l.shape, jnp.asarray(l).dtype.name)
+                           for l in leaves)
+            if i == 0:
+                treedef0, shapes0 = treedef, shapes
+            elif (treedef, shapes) != (treedef0, shapes0):
+                raise ValueError(
+                    f"launch_batch: request {i} does not match the batch "
+                    f"specialization (buffer structure or leaf shapes/dtypes "
+                    f"differ from request 0); only compatible launches stack")
+            packed.append(leaves)
+        n = len(packed)
+        stacked = tuple(jnp.stack([p[j] for p in packed])
+                        for j in range(len(packed[0])))
+        key = ("batch", n, backend, grid, block, grain, dyn_shared, interpret,
+               treedef0, shapes0)
+        per_kernel = _kernel_cache(kernel)
+        entry = per_kernel.get(key)
+        if entry is not None:
+            _STATS.hits += 1
+            _lru_touch(kernel, key)
+        else:
+            _STATS.misses += 1
+            with _miss_path():
+                fn = _build_batch(kernel, backend, grid, block, grain,
+                                  dyn_shared, treedef0, interpret)
+                # surface UnsupportedKernel eagerly, as the single-launch path
+                jax.eval_shape(fn, *stacked)
+                if backend == "pallas" and not interpret:
+                    mosaic_compile(fn, stacked)
+            entry = CompiledKernel(kernel=kernel, backend=backend, grid=grid,
+                                   block=block, key=key, fn=fn, source="trace")
+            per_kernel[key] = entry
+            _lru_insert(kernel, key)
+        out, _ = _dispatch(entry, stacked)
+        return [memory_mod.rebind_outputs(
+                    kernel, a, {name: v[i] for name, v in out.items()})
+                for i, a in enumerate(args_list)]
 
 
 def supported(kernel: KernelDef, backend: str, *, grid=4, block=64,

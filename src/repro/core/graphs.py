@@ -23,10 +23,16 @@ uses (paper Listing 4, extended stream-to-stream):
 Nodes in the same topological level have no path between them; the fused
 trace preserves only true dataflow, so XLA is free to schedule them in
 parallel - the "batching" of independent nodes.
+
+The replay's device program is named for the kernels it holds
+(``jit_<kernel>__<backend>[__...]__graph``), and each kernel node runs
+under ``jax.named_scope(<kernel>)``, so fused kernels stay attributable
+op by op in a device trace.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Sequence
 
 import jax
@@ -307,7 +313,15 @@ class GraphExec:
             produced.update(n.writes)
         self.inputs = tuple(sorted(needed))
         self._host = [n.host for n in graph.nodes if n.kind == "h2d"]
-        self._jit = jax.jit(self._replay)
+
+        def replay(heap: dict, host: Sequence):
+            return self._replay(heap, host)
+
+        kernels = dict.fromkeys(api.program_name(n.kernel.name, n.backend)
+                                for n in graph.nodes if n.kind == "kernel")
+        replay.__name__ = replay.__qualname__ = api.program_name(
+            *kernels, "graph")
+        self._jit = jax.jit(replay)
 
     def _replay(self, heap: dict, host: Sequence):
         glob = dict(heap)
@@ -315,13 +329,14 @@ class GraphExec:
         for node in self.graph.nodes:
             if node.kind == "kernel":
                 entry = get_backend(node.backend)
-                out = entry.run(node.kernel, grid=node.grid,
-                                block=node.block, glob=dict(glob),
-                                grain=node.grain,
-                                dyn_shared=node.dyn_shared,
-                                interpret=node.interpret,
-                                **api.device_opts(entry, node.devices,
-                                                  node.shard_axis))
+                with jax.named_scope(api.program_name(node.kernel.name)):
+                    out = entry.run(node.kernel, grid=node.grid,
+                                    block=node.block, glob=dict(glob),
+                                    grain=node.grain,
+                                    dyn_shared=node.dyn_shared,
+                                    interpret=node.interpret,
+                                    **api.device_opts(entry, node.devices,
+                                                      node.shard_axis))
                 for b in node.writes:
                     glob[b] = out[b]
             elif node.kind == "h2d":
@@ -376,9 +391,16 @@ class GraphExec:
         self._host[i] = host
 
     def replay(self, buffers: dict) -> dict:
-        """Run the whole DAG as one dispatch; returns written buffers."""
+        """Run the whole DAG as one dispatch; returns written buffers.
+        The first replay compiles, and is counted in
+        :func:`repro.core.api.cache_stats` as a first dispatch."""
+        first = self.launches == 0
         self.launches += 1
-        return self._jit(self._heap_inputs(buffers), tuple(self._host))
+        t0 = time.perf_counter()
+        out = self._jit(self._heap_inputs(buffers), tuple(self._host))
+        if first:
+            api.count_first_dispatch(t0)
+        return out
 
     def launch(self, target) -> Any:
         """``cudaGraphLaunch``: replay onto a stream's (or runtime's

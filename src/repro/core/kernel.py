@@ -40,6 +40,9 @@ from repro.core.dim3 import Dim3
 
 WARP_SIZE = 32
 
+# host spans of chain replay (recorded only inside a profiler session)
+_span = jax.profiler.TraceAnnotation
+
 
 def expf(x):
     """CUDA ``expf``, accurate to an ulp or two.
@@ -437,6 +440,10 @@ class LaunchChain:
       dispatches (one dispatch for the whole chain when there is no stop
       flag).
 
+    Each host-driven iteration is a ``cupbop.chain.iteration`` span, each
+    stop-flag read ``cupbop.chain.stop`` and each graph replay
+    ``cupbop.graph.replay`` (profiler spans; :class:`ChainStats` counts).
+
     Stop-flag chains replayed in k-batched modes may overshoot
     convergence by up to ``check_every - 1`` iterations; such chains must
     be no-ops once converged (Rodinia BFS is: an empty frontier claims
@@ -464,12 +471,13 @@ class LaunchChain:
 
     def _stopped(self, bufs: dict) -> bool:
         """Read the stop predicate back to the host (THE host sync)."""
-        if self.device_stop is not None:
-            raw = {n: memory.unwrap(v) for n, v in bufs.items()}
-            return bool(np.asarray(self.device_stop(raw)))
-        if self.stop is not None:
-            return bool(self.stop(bufs))
-        return False
+        with _span("cupbop.chain.stop"):
+            if self.device_stop is not None:
+                raw = {n: memory.unwrap(v) for n, v in bufs.items()}
+                return bool(np.asarray(self.device_stop(raw)))
+            if self.stop is not None:
+                return bool(self.stop(bufs))
+            return False
 
     def _apply_update(self, step: ChainStep, bufs: dict) -> dict:
         raw = {n: memory.unwrap(v) for n, v in bufs.items()}
@@ -484,12 +492,13 @@ class LaunchChain:
                     stats.host_syncs += 1
                 if self._stopped(bufs):
                     break
-            for step in self.steps:
-                if step.prepare is not None:
-                    bufs = {**bufs, **step.prepare(it, bufs)}
-                bufs = {**bufs, **launch_step(step, bufs)}
-                if stats is not None:
-                    stats.launches += 1
+            with _span("cupbop.chain.iteration", it=it):
+                for step in self.steps:
+                    if step.prepare is not None:
+                        bufs = {**bufs, **step.prepare(it, bufs)}
+                    bufs = {**bufs, **launch_step(step, bufs)}
+                    if stats is not None:
+                        stats.launches += 1
             if stats is not None:
                 stats.iterations += 1
         return bufs
@@ -510,15 +519,16 @@ class LaunchChain:
                     stats.host_syncs += 1
                 if self._stopped(bufs):
                     break
-            for step in self.steps:
-                if step.update is not None:
-                    if it:
-                        bufs = self._apply_update(step, bufs)
-                elif step.prepare is not None:
-                    bufs = {**bufs, **step.prepare(it, bufs)}
-                bufs = {**bufs, **launch_step(step, bufs)}
-                if stats is not None:
-                    stats.launches += 1
+            with _span("cupbop.chain.iteration", it=it):
+                for step in self.steps:
+                    if step.update is not None:
+                        if it:
+                            bufs = self._apply_update(step, bufs)
+                    elif step.prepare is not None:
+                        bufs = {**bufs, **step.prepare(it, bufs)}
+                    bufs = {**bufs, **launch_step(step, bufs)}
+                    if stats is not None:
+                        stats.launches += 1
             if stats is not None:
                 stats.iterations += 1
         return bufs
@@ -580,7 +590,8 @@ class LaunchChain:
                     stats.launches += remaining * len(self.steps)
                 done = self.repeat
                 break
-            ex.launch(stream)
+            with _span("cupbop.graph.replay"):
+                ex.launch(stream)
             done += unit
             if stats is not None:
                 stats.iterations += unit

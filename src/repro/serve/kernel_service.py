@@ -41,10 +41,11 @@ up as a fault, not as low occupancy; the worker thread never dies with the
 service open.
 
 Observability: :meth:`KernelService.stats` snapshots a
-:class:`ServiceStats` - per-kernel p50/p99 latency, throughput, warm-hit
-rate (compile-cache hit fraction since service start), batch-occupancy
-histogram, and queue depth - the JSON surface ``benchmarks/servebench.py``
-feeds to the perf gate.
+:class:`ServiceStats` - per-kernel p50/p99 latency and queue wait,
+throughput, warm-hit rate (compile-cache hit fraction since service
+start), batch-occupancy histogram, and queue depth - the JSON surface
+``benchmarks/servebench.py`` feeds to the perf gate.  Each dispatch is a
+``cupbop.serve.dispatch`` profiler span (attributes ``endpoint``, ``n``).
 
 The token-level LM tier (:mod:`repro.serve.engine`) sits beside this
 module: same emit-on-hazard discipline, different request granularity
@@ -132,7 +133,7 @@ class ServeTicket:
     completes or fails it."""
 
     __slots__ = ("rid", "endpoint", "tenant", "args", "timeout", "key",
-                 "submitted_at", "finished_at", "batch_size",
+                 "submitted_at", "dispatched_at", "finished_at", "batch_size",
                  "_event", "_result", "_error")
 
     def __init__(self, rid: int, endpoint: str, tenant: str, args: dict,
@@ -140,6 +141,7 @@ class ServeTicket:
         self.rid, self.endpoint, self.tenant = rid, endpoint, tenant
         self.args, self.timeout, self.key = args, timeout, key
         self.submitted_at = time.monotonic()
+        self.dispatched_at: float | None = None
         self.finished_at: float | None = None
         self.batch_size = 0
         self._event = threading.Event()
@@ -264,6 +266,8 @@ class KernelService:
         self._batch_fallbacks = 0
         self._last_batch_error: str | None = None
         self._occupancy: collections.Counter = collections.Counter()
+        # per endpoint, (submit->emit, submit->dispatch) seconds of each
+        # completed request
         self._latency: dict[str, collections.deque] = {}
         if autostart:
             self.start()
@@ -436,34 +440,40 @@ class KernelService:
 
     # -- dispatch ------------------------------------------------------------
     def _dispatch(self, batch: list[ServeTicket]):
-        ep = self._endpoints[batch[0].endpoint]
-        if len(batch) > 1:
-            try:
-                outs = self._run_batch(ep, batch)
-            except Exception as e:      # noqa: BLE001 - isolation boundary
-                # a poisoned tenant (bad binding, sanitizer finding, ...)
-                # failed the stacked dispatch as a unit: fall through to
-                # independent dispatches so it only takes itself down
-                with self._lock:
-                    self._batch_fallbacks += 1
-                    self._last_batch_error = f"{type(e).__name__}: {e}"
-                    if isinstance(e, UnsupportedKernel):
-                        # the backend cannot stack this specialization:
-                        # later traffic skips straight to singles
-                        self._unbatchable.add(batch[0].key)
-            else:
-                self._record_dispatch(len(batch), batched=True)
-                for t, out in zip(batch, outs):
-                    self._complete(t, out, len(batch))
-                return
+        now = time.monotonic()
         for t in batch:
-            try:
-                out = self._run_one(ep, t)
-            except Exception as e:      # noqa: BLE001 - isolation boundary
-                self._fail(t, e)
-            else:
-                self._complete(t, out, 1)
-            self._record_dispatch(1, batched=False)
+            t.dispatched_at = now
+        with jax.profiler.TraceAnnotation("cupbop.serve.dispatch",
+                                          endpoint=batch[0].endpoint,
+                                          n=len(batch)):
+            ep = self._endpoints[batch[0].endpoint]
+            if len(batch) > 1:
+                try:
+                    outs = self._run_batch(ep, batch)
+                except Exception as e:      # noqa: BLE001 - isolation boundary
+                    # a poisoned tenant (bad binding, sanitizer finding, ...)
+                    # failed the stacked dispatch as a unit: fall through to
+                    # independent dispatches so it only takes itself down
+                    with self._lock:
+                        self._batch_fallbacks += 1
+                        self._last_batch_error = f"{type(e).__name__}: {e}"
+                        if isinstance(e, UnsupportedKernel):
+                            # the backend cannot stack this specialization:
+                            # later traffic skips straight to singles
+                            self._unbatchable.add(batch[0].key)
+                else:
+                    self._record_dispatch(len(batch), batched=True)
+                    for t, out in zip(batch, outs):
+                        self._complete(t, out, len(batch))
+                    return
+            for t in batch:
+                try:
+                    out = self._run_one(ep, t)
+                except Exception as e:      # noqa: BLE001 - isolation boundary
+                    self._fail(t, e)
+                else:
+                    self._complete(t, out, 1)
+                self._record_dispatch(1, batched=False)
 
     def _merged(self, ep: Endpoint, t: ServeTicket) -> dict:
         merged = {**ep.bound, **t.args}
@@ -543,7 +553,8 @@ class KernelService:
             self._completed += 1
             res = self._latency.setdefault(
                 t.endpoint, collections.deque(maxlen=_RESERVOIR))
-            res.append(t.finished_at - t.submitted_at)
+            res.append((t.finished_at - t.submitted_at,
+                        t.dispatched_at - t.submitted_at))
         t._result = result
         t._event.set()
 
@@ -564,12 +575,15 @@ class KernelService:
         with self._lock:
             kernels = {}
             for name, res in self._latency.items():
-                samples = [s * 1e3 for s in res]
+                samples = [s * 1e3 for s, _ in res]
+                queued = [q * 1e3 for _, q in res]
                 kernels[name] = {
                     "count": len(samples),
                     "p50_ms": round(_percentile(samples, 50), 4),
                     "p99_ms": round(_percentile(samples, 99), 4),
                     "mean_ms": round(float(np.mean(samples)), 4),
+                    "queue_p50_ms": round(_percentile(queued, 50), 4),
+                    "queue_p99_ms": round(_percentile(queued, 99), 4),
                 }
             return ServiceStats(
                 submitted=self._submitted, completed=self._completed,

@@ -356,3 +356,24 @@ def test_stats_json_roundtrips(kernel):
     assert parsed["completed"] == 1
     assert "vecadd" in parsed["kernels"]
     assert parsed["batch_occupancy"] == {"1": 1}
+
+
+def test_queue_wait_counts_from_submit_to_dispatch(kernel):
+    """Requests queued 50 ms before the worker starts wait at least that
+    long, and their wait is part of their latency."""
+    rng = np.random.default_rng(14)
+    svc = KernelService(backend="loop", autostart=False)
+    try:
+        svc.register("vecadd", kernel, grid=GRID, block=BLOCK)
+        tickets = [svc.submit("vecadd", vecadd_args(rng)) for _ in range(3)]
+        time.sleep(0.05)
+        svc.start()
+        for t in tickets:
+            t.result(timeout=120)
+        lat = svc.stats().kernels["vecadd"]
+        assert 50.0 <= lat["queue_p50_ms"] <= lat["p50_ms"]
+        assert lat["queue_p50_ms"] <= lat["queue_p99_ms"] <= lat["p99_ms"]
+        assert all(t.submitted_at < t.dispatched_at < t.finished_at
+                   for t in tickets)
+    finally:
+        svc.close()
