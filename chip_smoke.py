@@ -5,13 +5,15 @@ Drives the entry points users call, in one process:
 1. device check: refuses to run anywhere but on a TPU;
 2. suite: all 23 ``build_suite(1)`` entries through ``run_entry`` on the
    ``vector`` lowering, each against its NumPy oracle at the entry's own
-   tolerance; each entry is also tried on ``pallas`` (Mosaic, not the
-   interpreter) and reported as ``compiled+correct`` or
+   tolerance, with the block schedule it traced; each entry is also
+   tried on ``pallas`` (Mosaic, not the interpreter) and reported as
+   ``compiled+correct`` or
    ``unsupported: <reason>`` - a refusal is not a failure, a wrong answer is;
 3. hotspot at Rodinia's ``1024 2 4`` (a 1024x1024 grid, 4 iterations,
    inputs made from a seed in hotspot's file format) through the ``host``,
-   ``device`` and ``graph`` chain modes: each against the oracle, and the
-   three bit-identical on ``t_out``;
+   ``device`` and ``graph`` chain modes: each against the oracle, the
+   three bit-identical on ``t_out``, and the launch on the tiled block
+   schedule (``CacheStats.vector_tiled``);
 4. serving: a ``KernelService`` on ``vector`` with four single-launch
    endpoints (vecadd at 1M elements), two waves of concurrent requests,
    every answer checked, at least one stacked dispatch and no fallback of a
@@ -49,7 +51,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import UnsupportedKernel, api, compile_cache  # noqa: E402
+from repro.core import (UnsupportedKernel, api, compile_cache,  # noqa: E402
+                        lower_vector)
 from repro.core.conformance import oracle_check  # noqa: E402
 from repro.core.cuda_suite import (build_suite, entry_hotspot,  # noqa: E402
                                    entry_lavamd, run_entry)
@@ -114,11 +117,12 @@ def phase_suite(scale: int = 1) -> None:
     for e in build_suite(scale):
         rng = np.random.default_rng(SEED)
         args = e.make_args(rng)
-        out, want = run_entry(e, "vector", args=args)
+        with lower_vector.schedules() as traced:
+            out, want = run_entry(e, "vector", args=args)
         try:
             err = check(out, want, e.tol, f"{e.name} on vector")
-            print(f"suite {e.name:16s} vector ok max_err={err:.3g}",
-                  flush=True)
+            print(f"suite {e.name:16s} vector ok max_err={err:.3g} "
+                  f"schedule={sorted(set(traced))}", flush=True)
         except SmokeFailure as f:
             failures.append(str(f))
             print(f"suite {e.name:16s} vector FAIL {f}", flush=True)
@@ -149,6 +153,7 @@ def phase_hotspot(h: int, w: int, iters: int) -> None:
     print(f"hotspot {h}x{w} iters={iters}: inputs+oracle "
           f"{time.perf_counter() - t0:.2f}s", flush=True)
     t_out = {}
+    tiled0 = api.cache_stats().vector_tiled
     for mode in ("host", "device", "graph"):
         def go(mode=mode):
             return run_entry(e, "vector", args=args, chain_mode=mode,
@@ -162,6 +167,11 @@ def phase_hotspot(h: int, w: int, iters: int) -> None:
               f"max_err={err:.3g}", flush=True)
     if len(set(t_out.values())) != 1:
         raise SmokeFailure("hotspot: host/device/graph t_out bits differ")
+    tiled = api.cache_stats().vector_tiled - tiled0
+    print(f"hotspot CacheStats.vector_tiled +{tiled}", flush=True)
+    if tiled < 1:
+        raise SmokeFailure("hotspot: the launch did not take the tiled "
+                           "block schedule")
 
 
 def phase_serving(scale: int) -> None:

@@ -52,7 +52,7 @@ from repro.core import backends as backends_mod
 from repro.core import compile_cache
 from repro.core import grain as grain_mod
 from repro.core import memory as memory_mod
-from repro.core import packing, pallas_emit
+from repro.core import lower_vector, packing, pallas_emit
 from repro.core.backends import backend_names, get_backend, register_backend
 from repro.core.dim3 import Dim3
 from repro.core.kernel import CompiledKernel, KernelDef, UnsupportedKernel
@@ -99,6 +99,11 @@ class CacheStats:
     time whole launches of a specialization already dispatched before -
     the steady-state launch cost, sanitize and optimize passes included
     when they are on.
+
+    ``vector_tiled``/``vector_serial`` count traced specializations (plain
+    and batched entries; shard backends with a vector inner lowering
+    included) by the block schedule :mod:`repro.core.lower_vector` chose;
+    the entry's ``schedule`` says why a serial one stayed serial.
     """
 
     hits: int = 0
@@ -111,6 +116,8 @@ class CacheStats:
     first_call_s: float = 0.0
     warm_launches: int = 0
     warm_launch_s: float = 0.0
+    vector_tiled: int = 0
+    vector_serial: int = 0
 
 
 _STATS = CacheStats()
@@ -342,10 +349,11 @@ def _entry_for(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
         _lru_touch(kernel, key)
         return entry, leaves
     _STATS.misses += 1
-    with _miss_path():
+    with _miss_path() as traced:
         entry = _compile(kernel, backend, grid, block, grain, dyn_shared,
                          interpret, treedef, leaves, shapes, key, devices,
                          shard_axis, donate_idx)
+    _keep_schedule(entry, traced)
     per_kernel[key] = entry
     _lru_insert(kernel, key)
     return entry, leaves
@@ -354,13 +362,25 @@ def _entry_for(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
 @contextlib.contextmanager
 def _miss_path():
     """The ``cupbop.compile`` span; its host seconds go to
-    :attr:`CacheStats.trace_s`."""
+    :attr:`CacheStats.trace_s`.  Yields the block schedules of the vector
+    lowerings traced inside it."""
     t0 = time.perf_counter()
     try:
-        with _span("cupbop.compile"):
-            yield
+        with _span("cupbop.compile"), lower_vector.schedules() as traced:
+            yield traced
     finally:
         _STATS.trace_s += time.perf_counter() - t0
+
+
+def _keep_schedule(entry: CompiledKernel, traced: list[str]) -> None:
+    """Record a new entry's vector block schedule on it, and count it."""
+    if not traced:
+        return
+    entry.schedule = next((s for s in traced if s != "tiled"), "tiled")
+    if entry.schedule == "tiled":
+        _STATS.vector_tiled += 1
+    else:
+        _STATS.vector_serial += 1
 
 
 def count_first_dispatch(t0: float) -> None:
@@ -679,7 +699,7 @@ def launch_batch(kernel: KernelDef, *, grid, block, args_list: list[dict],
             _lru_touch(kernel, key)
         else:
             _STATS.misses += 1
-            with _miss_path():
+            with _miss_path() as traced:
                 fn = _build_batch(kernel, backend, grid, block, grain,
                                   dyn_shared, treedef0, interpret)
                 # surface UnsupportedKernel eagerly, as the single-launch path
@@ -688,6 +708,7 @@ def launch_batch(kernel: KernelDef, *, grid, block, args_list: list[dict],
                     mosaic_compile(fn, stacked)
             entry = CompiledKernel(kernel=kernel, backend=backend, grid=grid,
                                    block=block, key=key, fn=fn, source="trace")
+            _keep_schedule(entry, traced)
             per_kernel[key] = entry
             _lru_insert(kernel, key)
         out, _ = _dispatch(entry, stacked)

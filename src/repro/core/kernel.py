@@ -659,7 +659,10 @@ class CompiledKernel:
     ABI of :mod:`repro.core.packing`).  ``source`` records how the entry was
     produced - ``"trace"`` (cold trace+lower) or ``"disk"`` (deserialized
     artifact, the ``cudaModuleLoad`` path) - and ``hits`` counts warm
-    launches served by this entry.
+    launches served by this entry.  ``schedule`` is the block schedule the
+    ``vector`` lowering traced for it (:mod:`repro.core.lower_vector`):
+    ``"tiled"``, or ``"serial: <reason>"``; ``None`` where no vector
+    lowering was traced (other backends, disk artifacts).
     """
 
     kernel: KernelDef
@@ -670,6 +673,7 @@ class CompiledKernel:
     fn: Callable
     source: str = "trace"
     hits: int = 0
+    schedule: str | None = None
 
     def __call__(self, *leaves):
         self.hits += 1
