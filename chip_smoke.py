@@ -3,7 +3,7 @@
 Drives the entry points users call, in one process:
 
 1. device check: refuses to run anywhere but on a TPU;
-2. suite: all 23 ``build_suite(1)`` entries through ``run_entry`` on the
+2. suite: all 24 ``build_suite(1)`` entries through ``run_entry`` on the
    ``vector`` lowering, each against its NumPy oracle at the entry's own
    tolerance, with the block schedule it traced; each entry is also
    tried on ``pallas`` (Mosaic, not the interpreter) and reported as

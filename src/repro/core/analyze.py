@@ -1051,7 +1051,7 @@ def analyze_entry(entry, *, sample_blocks: int = 3,
                                dyn_shared=entry.dyn_shared,
                                sample_blocks=sample_blocks)]
     reports, done = [], set()
-    for step in entry.chain.steps:
+    for step in entry.chain.all_steps:
         report = analyze_kernel(step.kernel, grid=step.grid,
                                 block=step.block, args=args,
                                 dyn_shared=step.dyn_shared,
@@ -1070,7 +1070,7 @@ def analyze_entry(entry, *, sample_blocks: int = 3,
 
 def analyze_suite(*, names: Sequence[str] | None = None, scale: int = 1,
                   sample_blocks: int = 3) -> list[KernelReport]:
-    """Run kernelcheck across the CUDA suite (all 23 kernels by default)."""
+    """Run kernelcheck across the CUDA suite (every kernel by default)."""
     from repro.core import cuda_suite
     entries = cuda_suite.build_suite(scale=scale)
     if names:
@@ -1208,7 +1208,7 @@ def fusion_entry(entry, *, sample_blocks: int = 3, rng=None) -> list[dict]:
                                dyn_shared=entry.dyn_shared,
                                sample_blocks=sample_blocks)]
     artifacts, done = [], set()
-    for step in entry.chain.steps:
+    for step in entry.chain.all_steps:
         art = analyze_fusion(step.kernel, grid=step.grid, block=step.block,
                              args=args, dyn_shared=step.dyn_shared,
                              sample_blocks=sample_blocks)

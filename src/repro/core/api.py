@@ -104,6 +104,9 @@ class CacheStats:
     and batched entries; shard backends with a vector inner lowering
     included) by the block schedule :mod:`repro.core.lower_vector` chose;
     the entry's ``schedule`` says why a serial one stayed serial.
+    ``tiled_launches``/``serial_launches`` count launches (warm and cold,
+    a stacked batch once) of entries with a vector block schedule, by
+    that schedule.
     """
 
     hits: int = 0
@@ -118,6 +121,8 @@ class CacheStats:
     warm_launch_s: float = 0.0
     vector_tiled: int = 0
     vector_serial: int = 0
+    tiled_launches: int = 0
+    serial_launches: int = 0
 
 
 _STATS = CacheStats()
@@ -383,6 +388,14 @@ def _keep_schedule(entry: CompiledKernel, traced: list[str]) -> None:
         _STATS.vector_serial += 1
 
 
+def _count_launch(entry: CompiledKernel) -> None:
+    """Count a launch of ``entry`` by its vector block schedule."""
+    if entry.schedule == "tiled":
+        _STATS.tiled_launches += 1
+    elif entry.schedule is not None:
+        _STATS.serial_launches += 1
+
+
 def count_first_dispatch(t0: float) -> None:
     """Count the first dispatch of a new specialization (a launch entry,
     or a graph replay), begun at ``time.perf_counter()`` ``t0``, in
@@ -461,6 +474,7 @@ def _launch(kernel: KernelDef, grid: Dim3, block: Dim3, args: dict,
                                    grain, dyn_shared, interpret, pool,
                                    devices, shard_axis)
         out, first = _dispatch(entry, leaves)
+        _count_launch(entry)
         # donated handle-bound buffers come back as the SAME handle,
         # re-bound to the kernel's output (the CUDA in-place view);
         # everything else is a plain functional result
@@ -712,6 +726,7 @@ def launch_batch(kernel: KernelDef, *, grid, block, args_list: list[dict],
             per_kernel[key] = entry
             _lru_insert(kernel, key)
         out, _ = _dispatch(entry, stacked)
+        _count_launch(entry)
         return [memory_mod.rebind_outputs(
                     kernel, a, {name: v[i] for name, v in out.items()})
                 for i, a in enumerate(args_list)]

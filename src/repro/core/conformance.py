@@ -359,6 +359,7 @@ def build_cases() -> list[ConformanceCase]:
         ConformanceCase("backprop_layer", _make_from("backprop_layer")),
         ConformanceCase("lud_diag", _make_from("lud_diag")),
         ConformanceCase("srad_step", _make_from("srad_step")),
+        ConformanceCase("srad_v1", _make_from("srad_v1")),
         ConformanceCase("lavamd", _make_from("lavamd")),
         ConformanceCase("nn", _make_from("nn")),
         ConformanceCase("kmeans", _make_from("kmeans")),

@@ -24,6 +24,7 @@ the paper's Table II:
 | backprop_layer      | backprop                | barrier tree + __constant__, owned-slice writes |
 | lud_diag            | lud (diagonal step)     | many barriers, in-shared pivoting, owned-slice writes |
 | srad_step           | srad                    | stencil + two-phase global reduction chain |
+| srad_v1             | srad_v1                 | six kernels, tree reduction passes, host read-back per iteration |
 | lavamd              | lavaMD                  | neighbor-list gather into heavy __shared__, register demotion |
 | nn                  | nn                      | cane record-file ingest, chained two-level top-k arg-min |
 | kmeans              | kmeans                  | convergence chain, device-resident stop, irregular atomicAdd |
@@ -35,7 +36,9 @@ wavefront kernels iterate via :class:`repro.core.kernel.LaunchChain`
 (host-driven inter-launch dependencies), BFS claims nodes with
 ``atomicCAS`` visited flags and counts its next frontier with
 ``__syncthreads_count``, and the read-only inputs of bfs/backprop ride in
-``__constant__`` space (:class:`repro.core.memory.ConstArray`).  The last
+``__constant__`` space (:class:`repro.core.memory.ConstArray`).
+srad_v1 is Rodinia 3.1's srad_v1 at its own six kernels and host loop
+(``srad_step`` is a narrower two-kernel version of it).  The last
 five rows are the coverage sprint toward the paper's 69.6% Rodinia figure:
 lavaMD's neighbor-box traversal, nn/hotspot's file-driven input pipelines
 (:mod:`repro.core.rodinia_io`), kmeans' iterative-convergence chain with a
@@ -750,6 +753,209 @@ def make_srad_update(h: int, w: int, lam: float = 0.2, tile_y: int = 8,
 
 
 # --------------------------------------------------------------------------
+# srad_v1 (Rodinia 3.1 cuda/srad/srad_v1): the application's six kernels on
+# an Nr x Nc image stored column-major (element ei is row ei % Nr, column
+# ei // Nr), 512 threads a block, one thread per pixel.  ``extract`` and
+# ``compress`` map the image into and out of the exponential domain once
+# per job; each iteration runs ``prepare`` (the pixels and their squares
+# into sums/sums2), the ``reduce`` passes (a shared-memory tree per block,
+# each block's sum to its first slot, until one block remains), then the
+# host reads sums[0]/sums2[0] back and derives q0sqr, and ``srad``/``srad2``
+# apply the diffusion.  The float constants of Rodinia's kernels are double
+# literals; here every operation is float32.
+# --------------------------------------------------------------------------
+SRAD_THREADS = 512
+
+
+def _srad_pixel(ctx, ne: int):
+    """Thread's pixel ``ei``, a clamped copy safe to gather with, and
+    whether it is a pixel at all (``ei < Ne``)."""
+    ei = _gid(ctx)
+    return ei, jnp.minimum(ei, ne - 1), ei < ne
+
+
+def _srad_map(name: str, ne: int, fn) -> KernelDef:
+    """``I[ei] = fn(I[ei])`` in place: srad_v1's extract and compress."""
+    def stage(ctx, st):
+        ei, e, ok = _srad_pixel(ctx, ne)
+        img = st.glob["I"]
+        idx = jnp.where(ok, ei, OOB)
+        return st.set_glob(I=img.at[idx].set(fn(img[e]), mode="drop"))
+
+    return KernelDef(name, (stage,), writes=("I",), reads=("I",),
+                     combines={"I": "concat"},
+                     est_block_work=SRAD_THREADS * 4.0)
+
+
+def make_srad_extract(ne: int) -> KernelDef:
+    return _srad_map("extract", ne, lambda v: expf(v / 255.0))
+
+
+def make_srad_compress(ne: int) -> KernelDef:
+    return _srad_map("compress", ne, lambda v: logf(v) * 255.0)
+
+
+def make_srad_prepare(ne: int) -> KernelDef:
+    def stage(ctx, st):
+        ei, e, ok = _srad_pixel(ctx, ne)
+        v = st.glob["I"][e]
+        idx = jnp.where(ok, ei, OOB)
+        return st.set_glob(
+            sums=st.glob["sums"].at[idx].set(v, mode="drop"),
+            sums2=st.glob["sums2"].at[idx].set(v * v, mode="drop"))
+
+    return KernelDef("prepare", (stage,), writes=("sums", "sums2"),
+                     reads=("I", "sums", "sums2"),
+                     combines={"sums": "concat", "sums2": "concat"},
+                     est_block_work=SRAD_THREADS * 3.0)
+
+
+def make_srad_reduce(no: int, mul: int, blocks: int) -> KernelDef:
+    """One pass of Rodinia's ``reduce(Ne, no, mul, sums, sums2)`` over
+    ``blocks`` blocks: ``no`` values at stride ``mul``.  Each block sums
+    its values in a shared-memory tree (``psum[tx] += psum[tx - i/2]``
+    where ``(tx+1) % i == 0``, i = 2, 4, ... 512) and its last thread
+    writes the block's sum to ``sums[bx * mul * 512]``.  A partial last
+    block of ``nf`` values runs the tree over the largest power of two
+    ``df <= nf`` of them, and thread ``df - 1`` adds the remaining values
+    one by one, in order."""
+    nt = SRAD_THREADS
+    nf = no - (blocks - 1) * nt        # values of the last block
+    df = 1 << (nf.bit_length() - 1)
+    partial = nf != nt
+
+    def load(ctx, st):
+        ei = _gid(ctx)
+        src = jnp.minimum(ei, no - 1) * mul
+        ok = ei < no
+        return st.set_shared(**{
+            p: st.shared[p].at[ctx.tid].set(
+                jnp.where(ok, st.glob[g][src], 0.0))
+            for p, g in (("p1", "sums"), ("p2", "sums2"))})
+
+    def make_level(i):
+        def level(ctx, st):
+            tx = ctx.tid
+            active = (tx + 1) % i == 0
+            if partial:
+                in_tree = (tx < df) if i <= df else False
+                active &= (ctx.bid != blocks - 1) | in_tree
+            src = jnp.where(active, tx - i // 2, tx)
+            return st.set_shared(**{
+                p: st.shared[p].at[tx].set(
+                    jnp.where(active, st.shared[p][tx] + st.shared[p][src],
+                              st.shared[p][tx]))
+                for p in ("p1", "p2")})
+        return level
+
+    def store(ctx, st):
+        writer, vals = nt - 1, {p: st.shared[p][nt - 1] for p in ("p1", "p2")}
+        if partial:
+            last = ctx.bid == blocks - 1
+            writer = jnp.where(last, df - 1, writer)
+            for p in ("p1", "p2"):
+                rest = st.shared[p][df - 1]
+                for j in range(df, nf):
+                    rest = rest + st.shared[p][j]
+                vals[p] = jnp.where(last, rest, vals[p])
+        idx = jnp.where(ctx.tid == writer, ctx.bid * mul * nt, OOB)
+        return st.set_glob(
+            sums=st.glob["sums"].at[idx].set(vals["p1"], mode="drop"),
+            sums2=st.glob["sums2"].at[idx].set(vals["p2"], mode="drop"))
+
+    levels = []
+    i = 2
+    while i <= nt:
+        levels.append(make_level(i))
+        i *= 2
+    return KernelDef(
+        "reduce", (load, *levels, store), writes=("sums", "sums2"),
+        reads=("sums", "sums2"),
+        shared={"p1": ((nt,), jnp.float32), "p2": ((nt,), jnp.float32)},
+        combines={"sums": "concat", "sums2": "concat"},
+        est_block_work=nt * 20.0,
+    )
+
+
+def make_srad_srad(nr: int, ne: int) -> KernelDef:
+    """Rodinia's ``srad``: directional derivatives through the clamped
+    index tables, then the diffusion coefficient from ``q0sqr``."""
+    def stage(ctx, st):
+        ei, e, ok = _srad_pixel(ctx, ne)
+        row, col = e % nr, e // nr
+        img, g = st.glob["I"], st.glob
+        jc = img[e]
+        dn = img[g["iN"][row] + nr * col] - jc
+        ds = img[g["iS"][row] + nr * col] - jc
+        dw = img[row + nr * g["jW"][col]] - jc
+        de = img[row + nr * g["jE"][col]] - jc
+        g2 = (dn * dn + ds * ds + dw * dw + de * de) / (jc * jc)
+        lap = (dn + ds + dw + de) / jc
+        num = 0.5 * g2 - 0.0625 * (lap * lap)
+        den = 1.0 + 0.25 * lap
+        qsqr = num / (den * den)
+        q0 = g["q0sqr"][0]
+        den = (qsqr - q0) / (q0 * (1.0 + q0))
+        c = 1.0 / (1.0 + den)
+        c = jnp.where(c < 0, 0.0, jnp.where(c > 1, 1.0, c))
+        idx = jnp.where(ok, ei, OOB)
+        out = {n: g[n].at[idx].set(v, mode="drop")
+               for n, v in (("dN", dn), ("dS", ds), ("dW", dw), ("dE", de),
+                            ("c", c))}
+        return st.set_glob(**out)
+
+    writes = ("dN", "dS", "dW", "dE", "c")
+    return KernelDef("srad", (stage,), writes=writes,
+                     reads=("I", "iN", "iS", "jW", "jE", "q0sqr", *writes),
+                     combines=dict.fromkeys(writes, "concat"),
+                     est_block_work=SRAD_THREADS * 40.0)
+
+
+def make_srad_srad2(nr: int, ne: int, lam: float) -> KernelDef:
+    """Rodinia's ``srad2``: the divergence with the south and east
+    neighbours' coefficients, and the image updated in place."""
+    def stage(ctx, st):
+        ei, e, ok = _srad_pixel(ctx, ne)
+        row, col = e % nr, e // nr
+        g, c = st.glob, st.glob["c"]
+        cs = c[g["iS"][row] + nr * col]
+        ce = c[row + nr * g["jE"][col]]
+        d = (c[e] * g["dN"][e] + cs * g["dS"][e] + c[e] * g["dW"][e]
+             + ce * g["dE"][e])
+        v = g["I"][e] + (0.25 * lam) * d
+        idx = jnp.where(ok, ei, OOB)
+        return st.set_glob(I=g["I"].at[idx].set(v, mode="drop"))
+
+    return KernelDef("srad2", (stage,), writes=("I",),
+                     reads=("I", "c", "dN", "dS", "dW", "dE", "iS", "jE"),
+                     combines={"I": "concat"},
+                     est_block_work=SRAD_THREADS * 12.0)
+
+
+def srad_reduce_passes(ne: int) -> list[tuple[int, int, int]]:
+    """``(no, mul, blocks)`` of each reduce pass, as Rodinia's host loop
+    sets them: ``ceil(Ne / 512)`` blocks first, then the block sums of the
+    pass before, until one block remains."""
+    nt, passes = SRAD_THREADS, []
+    no, mul, blocks = ne, 1, -(-ne // SRAD_THREADS)
+    while True:
+        passes.append((no, mul, blocks))
+        if blocks == 1:
+            return passes
+        no, mul, blocks = blocks, mul * nt, -(-blocks // nt)
+
+
+def srad_q0sqr(total, total2, n):
+    """Rodinia's host statistics from the reduced sums over ``n`` pixels,
+    in float32: NumPy arrays on the host, device arrays in the
+    device-resident replay."""
+    mean = total / n
+    mean2 = mean * mean
+    var = total2 / n - mean2
+    return var / mean2
+
+
+# --------------------------------------------------------------------------
 # lavaMD (Rodinia lavaMD): per-box particle interactions over a neighbor
 # list.  Each block owns one home box; for every neighbor box it stages that
 # box's particle positions and charges into shared memory, barriers, and
@@ -1326,6 +1532,7 @@ def build_suite(scale: int = 1) -> list[SuiteEntry]:
     entries.append(entry_backprop_layer())
     entries.append(entry_lud_diag())
     entries.append(entry_srad_step(scale))
+    entries.append(entry_srad_v1())
     entries.append(entry_lavamd())
     entries.append(entry_nn())
     entries.append(entry_kmeans())
@@ -1612,6 +1819,82 @@ def entry_srad_step(scale: int = 1, iters: int = 2,
         "srad_step", ("barrier", "dim3", "chain"), stats_k, grid1, block,
         None, margs, ref, chain=chain, tol=1e-4, rodinia="srad",
         dim3_free=False,
+    )
+
+
+def entry_srad_v1(rows: int = 64, cols: int = 40, iters: int = 4,
+                  lam: float = 0.5) -> SuiteEntry:
+    """Rodinia 3.1 srad_v1 (``./srad iters lam rows cols``) on an 8-bit
+    image drawn from the generator, column-major: ``extract``, then
+    ``iters`` iterations of prepare, the reduce passes, the host's read of
+    the sums, srad and srad2, then ``compress``.  The default 64 x 40
+    image is five full blocks, so its second pass has a partial block."""
+    nr, ne = rows, rows * cols
+    blocks = -(-ne // SRAD_THREADS)
+    passes = srad_reduce_passes(ne)
+
+    def margs(r):
+        z = np.zeros(ne, np.float32)
+        i, j = np.arange(rows, dtype=np.int32), np.arange(cols, dtype=np.int32)
+        return {"I": r.integers(0, 256, ne).astype(np.float32),
+                "iN": np.maximum(i - 1, 0), "iS": np.minimum(i + 1, rows - 1),
+                "jW": np.maximum(j - 1, 0), "jE": np.minimum(j + 1, cols - 1),
+                "sums": z, "sums2": z, "dN": z, "dS": z, "dW": z, "dE": z,
+                "c": z, "q0sqr": np.zeros(1, np.float32)}
+
+    def ref(a):
+        img = np.exp(np.asarray(a["I"], np.float32).reshape(cols, rows)
+                     / np.float32(255))
+        for _ in range(iters):
+            total = np.float32(img.sum(dtype=np.float64))
+            total2 = np.float32((img.astype(np.float64) ** 2).sum())
+            q0 = srad_q0sqr(total, total2, np.float32(ne))
+            p = np.pad(img, 1, mode="edge")      # [column, row]
+            dn, ds = p[1:-1, :-2] - img, p[1:-1, 2:] - img
+            dw, de = p[:-2, 1:-1] - img, p[2:, 1:-1] - img
+            g2 = (dn * dn + ds * ds + dw * dw + de * de) / (img * img)
+            lap = (dn + ds + dw + de) / img
+            num = np.float32(0.5) * g2 - np.float32(0.0625) * (lap * lap)
+            den = np.float32(1) + np.float32(0.25) * lap
+            den = (num / (den * den) - q0) / (q0 * (np.float32(1) + q0))
+            c = np.clip(np.float32(1) / (np.float32(1) + den), 0, 1)
+            cp = np.pad(c, 1, mode="edge")
+            d = c * dn + cp[1:-1, 2:] * ds + c * dw + cp[2:, 1:-1] * de
+            img = img + np.float32(0.25 * lam) * d
+        return {"I": (np.log(img) * np.float32(255)).reshape(-1)}
+
+    def host_q0(it, bufs):
+        # bufs["sums"]/["sums2"]: the host copies of sums[0], sums2[0]
+        return {"q0sqr": jnp.asarray(srad_q0sqr(bufs["sums"], bufs["sums2"],
+                                                np.float32(ne)))}
+
+    def device_q0(bufs):
+        # the barrier keeps Ne a divisor: XLA turns a division by a
+        # constant into a product with its rounded reciprocal
+        n = lax.optimization_barrier(jnp.float32(ne))
+        return {"q0sqr": srad_q0sqr(bufs["sums"][:1], bufs["sums2"][:1], n)}
+
+    reduce_steps = [ChainStep(make_srad_reduce(no, mul, nb), nb,
+                              SRAD_THREADS)
+                    for no, mul, nb in passes]
+    reduce_steps[-1] = dataclasses.replace(
+        reduce_steps[-1], read={"sums": 1, "sums2": 1})
+    chain = LaunchChain(
+        before=(ChainStep(make_srad_extract(ne), blocks, SRAD_THREADS),),
+        steps=(ChainStep(make_srad_prepare(ne), blocks, SRAD_THREADS),
+               *reduce_steps,
+               ChainStep(make_srad_srad(nr, ne), blocks, SRAD_THREADS,
+                         prepare=host_q0, update=device_q0),
+               ChainStep(make_srad_srad2(nr, ne, lam), blocks,
+                         SRAD_THREADS)),
+        after=(ChainStep(make_srad_compress(ne), blocks, SRAD_THREADS),),
+        repeat=iters,
+    )
+    first = chain.before[0]
+    return SuiteEntry(
+        "srad_v1", ("barrier", "chain", "const"), first.kernel, blocks,
+        SRAD_THREADS, None, margs, ref, chain=chain,
+        const=("iN", "iS", "jW", "jE"), tol=1e-4, rodinia="srad_v1",
     )
 
 

@@ -26,6 +26,7 @@ chunk size.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 from typing import Any, Callable, Mapping, NamedTuple, Sequence
@@ -364,16 +365,28 @@ class ChainStep:
     src/dst pointers, re-zero a per-iteration accumulator).  It receives
     ``(iteration, buffers)`` and must not mutate ``buffers``.
 
+    ``read`` names buffers the host copies back after the launch, each
+    with the number of leading elements copied - Rodinia srad's
+    ``cudaMemcpy`` of the reduced sums is ``{"sums": 1, "sums2": 1}``.
+    The host-hop replay makes the copy, and the ``prepare`` of the next
+    step sees each such buffer as its host copy (a NumPy array of those
+    elements) in place of the device buffer; the heap keeps the device
+    buffer.
+
     ``update`` is the *device-resident* form of the same hook: a pure,
     traceable function of the buffer dict alone (``bufs -> overrides``,
     jnp ops only, no iteration number - per-iteration scalars live in
     small device buffers the update increments, e.g. ``level + 1``).
     Because it needs no host values it runs without any host round-trip
-    and captures into a graph as an update node.  The device-resident
+    and captures into a graph as an update node; where the host path
+    reads buffers back, ``update`` computes from the device buffers what
+    ``prepare`` computes from the host copies.  The device-resident
     contract: ``update`` is applied before every launch *except iteration
     0*, whose ``prepare`` must therefore be an identity (all the suite
     chains already satisfy this - their ``prepare(0, ...)`` re-states the
-    initial buffer values).
+    initial buffer values) - unless an earlier step of the iteration
+    reads buffers back: the step right after such a read computes from
+    it in every iteration, the first included.
     """
 
     kernel: "KernelDef"
@@ -382,25 +395,60 @@ class ChainStep:
     dyn_shared: int | None = None
     prepare: Callable[[int, dict], dict] | None = None
     update: Callable[[dict], dict] | None = None
+    read: Mapping[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
 class ChainStats:
-    """Replay counters for one :class:`LaunchChain` run.
+    """Replay counters for :class:`LaunchChain` runs.
 
     ``host_syncs`` counts host round-trips forced by the chain driver
     (stop-flag reads - the traffic the device-resident mode amortizes);
-    ``graph_replays`` counts fused graph dispatches in graph mode.
+    ``host_reads`` counts the copies of :attr:`ChainStep.read` buffers
+    back to the host (one per step that declares any, per iteration, in
+    host-hop replay only); ``graph_replays`` counts fused graph dispatches
+    in graph mode; ``runs`` counts whole chain runs.  A caller's
+    ``stats`` counts its own runs; :func:`chain_totals` counts every run
+    of the process.
     """
 
     iterations: int = 0
     launches: int = 0
     host_syncs: int = 0
+    host_reads: int = 0
     graph_replays: int = 0
+    runs: int = 0
 
     @property
     def syncs_per_iteration(self) -> float:
         return self.host_syncs / max(1, self.iterations)
+
+    def add(self, other: "ChainStats") -> None:
+        for f in dataclasses.fields(self):
+            setattr(self, f.name,
+                    getattr(self, f.name) + getattr(other, f.name))
+
+
+_TOTALS = ChainStats()
+
+
+def chain_totals() -> ChainStats:
+    """Counters of every :class:`LaunchChain` run of this process, as
+    ``api.cache_stats()`` keeps the launch cache's (a copy)."""
+    return dataclasses.replace(_TOTALS)
+
+
+@contextlib.contextmanager
+def _tally(stats: ChainStats | None):
+    """Count one chain run: yields its own :class:`ChainStats`, added to
+    ``stats`` (where given) and to the process totals when it ends."""
+    run = ChainStats(runs=1)
+    try:
+        yield run
+    finally:
+        _TOTALS.add(run)
+        if stats is not None:
+            stats.add(run)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -408,12 +456,17 @@ class LaunchChain:
     """Inter-launch dependency idiom for iterative wavefront kernels.
 
     Rodinia's wavefront codes (pathfinder, needle, bfs, srad) re-launch
-    one or two kernels from a host loop, each launch consuming the
+    one or more kernels from a host loop, each launch consuming the
     previous launch's writes - the dependency lives *between* launches,
     not between stages of one kernel.  A ``LaunchChain`` makes that idiom
     declarative: ``steps`` run in order, the whole sequence ``repeat``
     times, with ``stop(buffers)`` checked host-side between iterations
     (the analogue of Rodinia BFS reading back its ``stop`` flag).
+    ``before`` and ``after`` are launched once, ahead of the first
+    iteration and behind the last (srad's ``extract`` and ``compress``),
+    with no hooks.  Steps of one iteration may differ in geometry (srad's
+    reduction passes), and a step may read buffers back to the host
+    (:attr:`ChainStep.read`).
 
     The chain is backend-agnostic: the caller supplies ``launch_step``,
     which runs one :class:`ChainStep` under whatever backend/grain/device
@@ -426,14 +479,15 @@ class LaunchChain:
 
     Three replay modes, all bit-identical on the oracle outputs:
 
-    * :meth:`run` - the host-hop baseline: host ``prepare`` hooks, stop
-      flag read back **every** iteration (one host sync per iteration,
-      the traffic Polygeist-style GPU-to-CPU work shows dominating
-      translated-kernel runtime);
+    * :meth:`run` - the host-hop baseline: host ``prepare`` hooks, the
+      steps' ``read`` buffers copied back and the stop flag read back
+      **every** iteration (host syncs per iteration, the traffic
+      Polygeist-style GPU-to-CPU work shows dominating translated-kernel
+      runtime);
     * :meth:`run_device` - device-resident: ``update`` hooks keep the
-      inter-launch state on device and the stop flag (``device_stop``, a
-      device predicate) is polled only every ``check_every`` iterations,
-      so host syncs drop to O(1/k);
+      inter-launch state on device, nothing is read back, and the stop
+      flag (``device_stop``, a device predicate) is polled only every
+      ``check_every`` iterations, so host syncs drop to O(1/k);
     * :meth:`run_graph` - device-resident *and* graph-captured: the
       iteration body is captured once into a
       :class:`~repro.core.graphs.Graph` and replayed as fused jitted
@@ -441,8 +495,9 @@ class LaunchChain:
       flag).
 
     Each host-driven iteration is a ``cupbop.chain.iteration`` span, each
-    stop-flag read ``cupbop.chain.stop`` and each graph replay
-    ``cupbop.graph.replay`` (profiler spans; :class:`ChainStats` counts).
+    stop-flag read ``cupbop.chain.stop``, each copy of read buffers
+    ``cupbop.chain.read`` and each graph replay ``cupbop.graph.replay``
+    (profiler spans; :class:`ChainStats` counts).
 
     Stop-flag chains replayed in k-batched modes may overshoot
     convergence by up to ``check_every - 1`` iterations; such chains must
@@ -457,6 +512,13 @@ class LaunchChain:
     stop: Callable[[dict], bool] | None = None
     device_stop: Callable[[dict], Any] | None = None
     check_every: int = 1
+    before: Sequence[ChainStep] = ()
+    after: Sequence[ChainStep] = ()
+
+    @property
+    def all_steps(self) -> tuple[ChainStep, ...]:
+        """Every step in launch order: ``before``, ``steps``, ``after``."""
+        return (*self.before, *self.steps, *self.after)
 
     def _has_stop(self) -> bool:
         return self.stop is not None or self.device_stop is not None
@@ -469,6 +531,15 @@ class LaunchChain:
                     f"without a device update; graph capture needs on-device "
                     f"inter-launch state (declare ChainStep.update)")
 
+    def _updates(self, it: int) -> list[bool]:
+        """Per step, whether its ``update`` applies in iteration ``it``:
+        after the first iteration always, in it only after a read."""
+        out, read = [], False
+        for step in self.steps:
+            out.append(step.update is not None and (it > 0 or read))
+            read = read or bool(step.read)
+        return out
+
     def _stopped(self, bufs: dict) -> bool:
         """Read the stop predicate back to the host (THE host sync)."""
         with _span("cupbop.chain.stop"):
@@ -479,29 +550,51 @@ class LaunchChain:
                 return bool(self.stop(bufs))
             return False
 
+    @staticmethod
+    def _read(step: ChainStep, bufs: dict) -> dict:
+        """Copy the leading elements of ``step.read``'s buffers back to
+        the host (the ``cudaMemcpy`` of Rodinia's host loop)."""
+        with _span("cupbop.chain.read"):
+            return jax.device_get({
+                n: memory.unwrap(bufs[n]).reshape(-1)[:count]
+                for n, count in step.read.items()})
+
     def _apply_update(self, step: ChainStep, bufs: dict) -> dict:
         raw = {n: memory.unwrap(v) for n, v in bufs.items()}
         return {**bufs, **step.update(raw)}
 
+    def _launch_once(self, steps, launch_step, bufs: dict,
+                     run: ChainStats) -> dict:
+        for step in steps:
+            bufs = {**bufs, **launch_step(step, bufs)}
+            run.launches += 1
+        return bufs
+
     def run(self, launch_step: Callable[[ChainStep, dict], dict],
             bufs: dict, stats: ChainStats | None = None) -> dict:
-        """Host-hop replay: host prepare hooks, stop checked per iteration."""
-        for it in range(self.repeat):
-            if it and self._has_stop():
-                if stats is not None:
-                    stats.host_syncs += 1
-                if self._stopped(bufs):
-                    break
-            with _span("cupbop.chain.iteration", it=it):
-                for step in self.steps:
-                    if step.prepare is not None:
-                        bufs = {**bufs, **step.prepare(it, bufs)}
-                    bufs = {**bufs, **launch_step(step, bufs)}
-                    if stats is not None:
-                        stats.launches += 1
-            if stats is not None:
-                stats.iterations += 1
-        return bufs
+        """Host-hop replay: host prepare hooks and reads, stop checked per
+        iteration."""
+        with _tally(stats) as run:
+            bufs = self._launch_once(self.before, launch_step, bufs, run)
+            host: dict = {}
+            for it in range(self.repeat):
+                if it and self._has_stop():
+                    run.host_syncs += 1
+                    if self._stopped(bufs):
+                        break
+                with _span("cupbop.chain.iteration", it=it):
+                    for step in self.steps:
+                        if step.prepare is not None:
+                            bufs = {**bufs,
+                                    **step.prepare(it, {**bufs, **host})}
+                        bufs = {**bufs, **launch_step(step, bufs)}
+                        run.launches += 1
+                        host = {}
+                        if step.read:
+                            host = self._read(step, bufs)
+                            run.host_reads += 1
+                run.iterations += 1
+            return self._launch_once(self.after, launch_step, bufs, run)
 
     def run_device(self, launch_step: Callable[[ChainStep, dict], dict],
                    bufs: dict, *, check_every: int | None = None,
@@ -510,41 +603,42 @@ class LaunchChain:
 
         Steps with an ``update`` hook never call their host ``prepare``;
         steps with only a legacy ``prepare`` still work (but reintroduce
-        the host hop they encode).
+        the host hop they encode).  Nothing is read back.
         """
         k = max(1, self.check_every if check_every is None else check_every)
-        for it in range(self.repeat):
-            if it and self._has_stop() and it % k == 0:
-                if stats is not None:
-                    stats.host_syncs += 1
-                if self._stopped(bufs):
-                    break
-            with _span("cupbop.chain.iteration", it=it):
-                for step in self.steps:
-                    if step.update is not None:
-                        if it:
-                            bufs = self._apply_update(step, bufs)
-                    elif step.prepare is not None:
-                        bufs = {**bufs, **step.prepare(it, bufs)}
-                    bufs = {**bufs, **launch_step(step, bufs)}
-                    if stats is not None:
-                        stats.launches += 1
-            if stats is not None:
-                stats.iterations += 1
-        return bufs
+        with _tally(stats) as run:
+            bufs = self._launch_once(self.before, launch_step, bufs, run)
+            for it in range(self.repeat):
+                if it and self._has_stop() and it % k == 0:
+                    run.host_syncs += 1
+                    if self._stopped(bufs):
+                        break
+                with _span("cupbop.chain.iteration", it=it):
+                    for step, upd in zip(self.steps, self._updates(it),
+                                         strict=True):
+                        if step.update is not None:
+                            if upd:
+                                bufs = self._apply_update(step, bufs)
+                        elif step.prepare is not None:
+                            bufs = {**bufs, **step.prepare(it, bufs)}
+                        bufs = {**bufs, **launch_step(step, bufs)}
+                        run.launches += 1
+                run.iterations += 1
+            return self._launch_once(self.after, launch_step, bufs, run)
 
     def run_graph(self, stream, *, check_every: int | None = None,
                   stats: ChainStats | None = None, **launch_kw) -> dict:
         """Graph-captured device-resident replay.
 
-        Iteration 0 launches eagerly (its prepare is identity by the
-        device-resident contract); the remaining iterations are captured
+        ``before`` and iteration 0 launch eagerly (iteration 0's prepare
+        is identity by the device-resident contract, or its update follows
+        a read and applies); the remaining iterations are captured
         *once* as a graph unit - ``update`` hooks become update nodes,
-        launches kernel nodes - and replayed.  Without a stop flag the
-        unit is all ``repeat - 1`` remaining iterations: the whole chain
-        collapses to one fused jitted dispatch.  With a stop flag the
-        unit is ``check_every`` iterations and the predicate is polled
-        once per replay.
+        launches kernel nodes - and replayed; ``after`` launches eagerly.
+        Without a stop flag the unit is all ``repeat - 1`` remaining
+        iterations: the whole chain collapses to one fused jitted
+        dispatch.  With a stop flag the unit is ``check_every``
+        iterations and the predicate is polled once per replay.
 
         ``stream`` supplies the capture surface and the heap;
         ``launch_kw`` (backend/grain/devices/...) reaches every captured
@@ -552,14 +646,30 @@ class LaunchChain:
         cannot be captured and raise :class:`UnsupportedKernel`.
         """
         self._require_device_resident()
-        for step in self.steps:
+        with _tally(stats) as run:
+            self._stream_steps(stream, self.before, (), **launch_kw)
+            self._stream_steps(stream, self.steps, self._updates(0),
+                               **launch_kw)
+            run.iterations += 1
+            run.launches += len(self.before) + len(self.steps)
+            if self.repeat > 1:
+                self._replay(stream, run, check_every, **launch_kw)
+            self._stream_steps(stream, self.after, (), **launch_kw)
+            run.launches += len(self.after)
+            return dict(stream.buffers)
+
+    def _stream_steps(self, stream, steps, updates, **launch_kw) -> None:
+        """Launch ``steps`` on ``stream``, each after its device update
+        where ``updates`` says so."""
+        for i, step in enumerate(steps):
+            if i < len(updates) and updates[i]:
+                stream.device_update(step.update)
             stream.launch(step.kernel, grid=step.grid, block=step.block,
                           dyn_shared=step.dyn_shared, **launch_kw)
-        if stats is not None:
-            stats.iterations += 1
-            stats.launches += len(self.steps)
-        if self.repeat <= 1:
-            return dict(stream.buffers)
+
+    def _replay(self, stream, run: ChainStats, check_every: int | None,
+                **launch_kw) -> None:
+        """Iterations 1 .. ``repeat - 1`` of :meth:`run_graph`."""
         k = max(1, self.check_every if check_every is None else check_every)
         unit = min(k, self.repeat - 1) if self._has_stop() \
             else self.repeat - 1
@@ -567,8 +677,7 @@ class LaunchChain:
         done = 1
         while done < self.repeat:
             if done > 1 and self._has_stop():
-                if stats is not None:
-                    stats.host_syncs += 1
+                run.host_syncs += 1
                 if self._stopped(stream.buffers):
                     break
             remaining = self.repeat - done
@@ -578,26 +687,17 @@ class LaunchChain:
                 # overshoot by unit - remaining real iterations, diverging
                 # from run()/run_device() on any non-converged chain)
                 for _ in range(remaining):
-                    for step in self.steps:
-                        if step.update is not None:
-                            stream.device_update(step.update)
-                        stream.launch(step.kernel, grid=step.grid,
-                                      block=step.block,
-                                      dyn_shared=step.dyn_shared,
-                                      **launch_kw)
-                if stats is not None:
-                    stats.iterations += remaining
-                    stats.launches += remaining * len(self.steps)
-                done = self.repeat
+                    self._stream_steps(stream, self.steps, self._updates(1),
+                                       **launch_kw)
+                run.iterations += remaining
+                run.launches += remaining * len(self.steps)
                 break
             with _span("cupbop.graph.replay"):
                 ex.launch(stream)
             done += unit
-            if stats is not None:
-                stats.iterations += unit
-                stats.launches += unit * len(self.steps)
-                stats.graph_replays += 1
-        return dict(stream.buffers)
+            run.iterations += unit
+            run.launches += unit * len(self.steps)
+            run.graph_replays += 1
 
     def capture_unit(self, stream, iterations: int, **launch_kw):
         """Capture ``iterations`` chain iterations into one reusable
@@ -613,11 +713,8 @@ class LaunchChain:
         self._require_device_resident()
         graph = stream.begin_capture()
         for _ in range(iterations):
-            for step in self.steps:
-                if step.update is not None:
-                    stream.device_update(step.update)
-                stream.launch(step.kernel, grid=step.grid, block=step.block,
-                              dyn_shared=step.dyn_shared, **launch_kw)
+            self._stream_steps(stream, self.steps, self._updates(1),
+                               **launch_kw)
         stream.end_capture()
         return graph.instantiate(stream.buffers)
 

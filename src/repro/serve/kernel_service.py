@@ -122,7 +122,7 @@ class Endpoint:
     def writes(self) -> tuple:
         if self.chain is not None:
             names: dict = {}
-            for step in self.chain.steps:
+            for step in self.chain.all_steps:
                 names.update(dict.fromkeys(step.kernel.writes))
             return tuple(names)
         return tuple(self.kernel.writes)
@@ -288,7 +288,7 @@ class KernelService:
             raise ServiceError(f"endpoint {name!r} is already registered")
         bound = dict(bound or {})
         if chain is not None:
-            kernels = [s.kernel for s in chain.steps]
+            kernels = [s.kernel for s in chain.all_steps]
         else:
             kernels = [kernel]
         touched: set = set()

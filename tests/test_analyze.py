@@ -1,6 +1,6 @@
 """kernelcheck (repro.core.analyze): races, declaration audit, fusion.
 
-Two halves: (1) the whole 23-kernel suite must come back *clean* - the
+Two halves: (1) the whole 24-kernel suite must come back *clean* - the
 declarations the runtime trusts (reads/writes/combines/donates) are
 verified, not assumed - and (2) deliberately broken fixture kernels must
 trip each finding kind with the right kernel/stage/buffer named, because a

@@ -1,4 +1,4 @@
-"""Differential conformance harness: the 23-kernel backend-agreement matrix.
+"""Differential conformance harness: the 24-kernel backend-agreement matrix.
 
 The per-cell tests here are the tier-1 face of the acceptance criterion:
 every suite kernel passes its NumPy oracle under loop/vector/shard/

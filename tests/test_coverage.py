@@ -171,11 +171,11 @@ def test_json_artifact_written_even_when_gate_fails(tmp_path, monkeypatch):
 
 
 def test_committed_baseline_matches_suite_shape():
-    """The checked-in baseline must describe the real 23-kernel suite with
+    """The checked-in baseline must describe the real 24-kernel suite with
     percent entries for every backend (hand-edit guard)."""
     with open(os.path.join(_BENCH, "coverage_baseline.json")) as f:
         base = json.load(f)
-    assert base["n_kernels"] == 23
+    assert base["n_kernels"] == 24
     assert set(base["percent"]) == set(base["backends"])
     for fw, cnt in base["backends"].items():
         assert base["percent"][fw] == round(100.0 * cnt / base["n_kernels"], 1)

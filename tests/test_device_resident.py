@@ -22,7 +22,8 @@ from repro.core.kernel import (
 )
 
 SUITE = {e.name: e for e in build_suite(scale=1)}
-CHAIN_NAMES = ("bfs_frontier", "pathfinder", "needle_nw", "srad_step")
+CHAIN_NAMES = ("bfs_frontier", "pathfinder", "needle_nw", "srad_step",
+               "srad_v1")
 
 
 def _compare(entry, host_out, out, context):
@@ -103,7 +104,7 @@ def test_host_syncs_drop_to_one_in_k():
 def test_fixed_repeat_chain_graph_is_single_dispatch():
     """Without a stop flag the whole remaining chain fuses into ONE graph
     replay - zero mid-chain host syncs."""
-    for name in ("pathfinder", "needle_nw", "srad_step"):
+    for name in ("pathfinder", "needle_nw", "srad_step", "srad_v1"):
         stats = ChainStats()
         run_entry(SUITE[name], "loop", chain_mode="graph",
                   chain_stats=stats)

@@ -3,7 +3,7 @@
 The TPU compiler is installed with JAX and compiles for a topology that is
 described, not attached.  These tests compile the ``vector`` lowering at
 deployment sizes (Rodinia's hotspot 1024x1024, nw 2048, pathfinder at
-102,400 columns) for one v5e chip, and pin the ``pallas`` rule: without
+102,400 columns, srad_v1's six kernels at 502x458) for one v5e chip, and pin the ``pallas`` rule: without
 interpret mode Mosaic refuses the suite's gather-mode kernels, and the
 launch path reports that refusal as ``UnsupportedKernel``.
 
@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import api, memory, packing
 from repro.core.cuda_suite import (entry_hotspot, entry_needle_nw,
-                                   entry_pathfinder, make_vecadd)
+                                   entry_pathfinder, entry_srad_v1,
+                                   make_vecadd)
 from repro.core.dim3 import Dim3
 from repro.core.kernel import UnsupportedKernel
 
@@ -85,6 +86,26 @@ def test_vector_compiles_for_one_v5e_at_deployment_size(build, one_chip):
     assert mem.argument_size_in_bytes >= sum(
         np.asarray(v).nbytes for v in args.values())
     assert mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_srad_v1_compiles_for_one_v5e_at_deployment_size(one_chip):
+    """Every launch specialization of ``./srad 100 0.5 502 458``: extract,
+    prepare, both reduce passes (450 blocks, then one), srad, srad2 and
+    compress, each on the block schedule it takes on the CPU."""
+    entry = entry_srad_v1(502, 458, iters=100)
+    args = entry.make_args(np.random.default_rng(0))
+    schedules = {}
+    for step in entry.chain.all_steps:
+        ck = api.compiled(step.kernel, grid=step.grid, block=step.block,
+                          args=args, backend="vector")
+        schedules.setdefault(step.kernel.name, set()).add(
+            ck.schedule.split(":")[0])
+        compiled = ck.fn.lower(*_chip_shapes(step.kernel, args,
+                                             one_chip)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    assert schedules == {"extract": {"serial"}, "prepare": {"tiled"},
+                         "reduce": {"serial"}, "srad": {"tiled"},
+                         "srad2": {"serial"}, "compress": {"serial"}}
 
 
 def test_pallas_mosaic_refusal_is_unsupported(one_chip):

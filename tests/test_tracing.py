@@ -182,11 +182,11 @@ def _inside(inner, outer) -> bool:
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """One profiler session around a cold launch, a host-mode chain with a
-    stop flag, a stacked batch, a graph-replayed chain and one service
-    dispatch."""
+    stop flag and a read back per iteration, a stacked batch, a
+    graph-replayed chain and one service dispatch."""
     api.cache_clear()
     k, args = make_vecadd(), vecadd_args()
-    chain = LaunchChain(steps=(ChainStep(k, GRID, BLOCK,
+    chain = LaunchChain(steps=(ChainStep(k, GRID, BLOCK, read={"c": 1},
                                          update=lambda b: {}),),
                         repeat=3, stop=lambda b: False)
     log_dir = str(tmp_path_factory.mktemp("trace"))
@@ -217,6 +217,7 @@ def _named(traced, name) -> list:
     ("cupbop.launch_batch", ("cupbop.serve.dispatch", None)),
     ("cupbop.chain.iteration", (None,)),
     ("cupbop.chain.stop", (None,)),
+    ("cupbop.chain.read", ("cupbop.chain.iteration",)),
     ("cupbop.graph.replay", (None,)),
     ("cupbop.serve.dispatch", (None,)),
 ])
@@ -236,6 +237,8 @@ def test_span_is_recorded_and_nested(traced, inner, outer):
 def test_spans_count_what_ran(traced):
     assert len(_named(traced, "cupbop.chain.iteration")) == 3
     assert len(_named(traced, "cupbop.chain.stop")) == 2 + 1
+    # the host-mode chain reads back once an iteration, the graph never
+    assert len(_named(traced, "cupbop.chain.read")) == 3
     # cold launch, 3 chain launches, graph chain's first eager launch and
     # tail, one service request
     assert len(_named(traced, "cupbop.launch")) >= 5

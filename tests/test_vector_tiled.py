@@ -28,6 +28,8 @@ SCHEDULES = {
     "backprop_layer": {"tiled"},
     "lud_diag": {"tiled"},
     "srad_step": {"serial: float reduce_sum in the block", "tiled"},
+    "srad_v1": {"serial: reads written buffer 'I'",
+                "serial: reads written buffer 'sums'", "tiled"},
     "lavamd": {"serial: float reduce_sum in the block"},
     "nn": {"tiled"},
     "kmeans": {"serial: reads written buffer 'assign'",
@@ -270,3 +272,46 @@ def test_cache_stats_count_each_specialization_once(fresh_cache):
     api.cache_clear()
     assert (api.cache_stats().vector_tiled,
             api.cache_stats().vector_serial) == (0, 0)
+
+
+# srad_v1's six kernels: the in-place ones and the reduction read what they
+# write; prepare and srad write owned slices only
+SRAD_V1_SCHEDULES = {
+    "extract": "serial: reads written buffer 'I'",
+    "prepare": "tiled",
+    "reduce": "serial: reads written buffer 'sums'",
+    "srad": "tiled",
+    "srad2": "serial: reads written buffer 'I'",
+    "compress": "serial: reads written buffer 'I'",
+}
+
+
+def test_srad_v1_schedule_of_each_kernel(fresh_cache):
+    e = [e for e in SUITE if e.name == "srad_v1"][0]
+    run_entry(e, "vector", with_reference=False)
+    got = {}
+    for step in e.chain.all_steps:
+        for entry in step.kernel._launch_cache.values():
+            got.setdefault(step.kernel.name, set()).add(entry.schedule)
+    assert got == {k: {v} for k, v in SRAD_V1_SCHEDULES.items()}
+
+
+def test_launches_are_counted_by_schedule(fresh_cache):
+    """Every launch counts, warm ones too: srad_v1 runs extract and
+    compress once and five steps an iteration, prepare and srad tiled."""
+    e = [e for e in SUITE if e.name == "srad_v1"][0]
+    hot = [e for e in SUITE if e.name == "hotspot"][0]
+    for _ in range(2):
+        run_entry(e, "vector", with_reference=False)
+    st = api.cache_stats()
+    iters = e.chain.repeat
+    assert (st.tiled_launches, st.serial_launches) == (
+        2 * 2 * iters, 2 * (2 + 3 * iters))
+    run_entry(hot, "vector", with_reference=False)
+    run_entry(hot, "loop", with_reference=False)
+    st2 = api.cache_stats()
+    assert st2.serial_launches == st.serial_launches
+    assert st2.tiled_launches == st.tiled_launches + hot.chain.repeat
+    api.cache_clear()
+    assert (api.cache_stats().tiled_launches,
+            api.cache_stats().serial_launches) == (0, 0)
