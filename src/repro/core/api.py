@@ -106,7 +106,8 @@ class CacheStats:
     the entry's ``schedule`` says why a serial one stayed serial.
     ``tiled_launches``/``serial_launches`` count launches (warm and cold,
     a stacked batch once) of entries with a vector block schedule, by
-    that schedule.
+    that schedule; ``owned_read_launches`` counts the tiled ones whose
+    blocks read the buffers they write (``"tiled: owned reads of ..."``).
     """
 
     hits: int = 0
@@ -123,6 +124,7 @@ class CacheStats:
     vector_serial: int = 0
     tiled_launches: int = 0
     serial_launches: int = 0
+    owned_read_launches: int = 0
 
 
 _STATS = CacheStats()
@@ -382,7 +384,7 @@ def _keep_schedule(entry: CompiledKernel, traced: list[str]) -> None:
     if not traced:
         return
     entry.schedule = next((s for s in traced if s != "tiled"), "tiled")
-    if entry.schedule == "tiled":
+    if entry.schedule.startswith("tiled"):
         _STATS.vector_tiled += 1
     else:
         _STATS.vector_serial += 1
@@ -390,9 +392,12 @@ def _keep_schedule(entry: CompiledKernel, traced: list[str]) -> None:
 
 def _count_launch(entry: CompiledKernel) -> None:
     """Count a launch of ``entry`` by its vector block schedule."""
-    if entry.schedule == "tiled":
+    if entry.schedule is None:
+        return
+    if entry.schedule.startswith("tiled"):
         _STATS.tiled_launches += 1
-    elif entry.schedule is not None:
+        _STATS.owned_read_launches += entry.schedule != "tiled"
+    else:
         _STATS.serial_launches += 1
 
 

@@ -758,7 +758,8 @@ class CompiledKernel:
     artifact, the ``cudaModuleLoad`` path) - and ``hits`` counts warm
     launches served by this entry.  ``schedule`` is the block schedule the
     ``vector`` lowering traced for it (:mod:`repro.core.lower_vector`):
-    ``"tiled"``, or ``"serial: <reason>"``; ``None`` where no vector
+    ``"tiled"``, ``"tiled: owned reads of [<buffers>]"`` or
+    ``"serial: <reason>"``; ``None`` where no vector
     lowering was traced (other backends, disk artifacts).
     """
 
