@@ -40,7 +40,7 @@ def main():
     print(f"reorder_loop_speedup,{lp:.2f},contiguous wins under scalar "
           f"threads (paper Table VI)")
     print(f"reorder_vector_speedup,{vc:.2f},coalesced wins under lane "
-          f"vectorization (TPU adaptation, DESIGN.md S2)")
+          f"vectorization (the TPU's vector lowering)")
 
 
 if __name__ == "__main__":

@@ -1,4 +1,4 @@
-"""CUDA atomics, adapted for TPU (DESIGN.md S2, deviation #2).
+"""CUDA atomics, adapted for TPU.
 
 TPU Pallas exposes no global-memory atomics.  The semantics-preserving
 adaptation relies on two facts of the lowered execution model:

@@ -1,4 +1,4 @@
-"""TPU-native SPMD-to-MPMD **vector** lowering (DESIGN.md S2, beyond-paper).
+"""TPU-native SPMD-to-MPMD **vector** lowering, an addition to the paper's.
 
 The whole thread block becomes one chunk: the thread axis is carried as the
 leading array axis of every private value and maps onto VPU lanes.  Barriers

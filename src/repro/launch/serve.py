@@ -1,17 +1,11 @@
-"""Serving driver: kernel-service traffic (default) or the LM engine (--lm).
+"""Serving driver: kernel-service traffic.
 
-The default mode stands up a :class:`repro.serve.KernelService`, registers
-the single-launch suite kernels as endpoints, replays a round-robin
-request mix through the batching worker, and prints the
+It stands up a :class:`repro.serve.KernelService`, registers the
+single-launch suite kernels as endpoints, replays a round-robin request
+mix through the batching worker, and prints the
 :class:`~repro.serve.ServiceStats` surface::
 
   PYTHONPATH=src python -m repro.launch.serve --smoke
-
-``--lm`` drives the token-level tier instead (continuous-batching decode
-over the transformer stack, :mod:`repro.serve.engine`)::
-
-  PYTHONPATH=src python -m repro.launch.serve --lm --arch qwen2-0.5b \\
-      --requests 8 --max-new 12
 """
 from __future__ import annotations
 
@@ -65,46 +59,11 @@ def serve_kernels(args) -> dict:
     return doc
 
 
-def serve_lm(args) -> dict:
-    """Batched LM requests through the continuous-batching engine."""
-    import jax
-
-    from repro.configs import registry
-    from repro.core.streams import Policy
-    from repro.models import transformer as T
-    from repro.serve.engine import Engine
-
-    cfg = registry.smoke(args.arch) if args.smoke else registry.get(args.arch)
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
-    policy = Policy.SYNC_ALWAYS if args.sync_always else Policy.HAZARD_ONLY
-    eng = Engine(cfg, params, slots=args.slots,
-                 max_len=args.prompt_len + args.max_new + 8, policy=policy)
-
-    rng = np.random.default_rng(0)
-    reqs = [eng.submit(rng.integers(0, cfg.vocab_size, args.prompt_len),
-                       max_new=args.max_new)
-            for _ in range(args.requests)]
-    t0 = time.time()
-    eng.run()
-    dt = time.time() - t0
-    toks = sum(len(r.out) for r in reqs)
-    print(f"served {len(reqs)} requests, {toks} tokens in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s) policy={policy.value} "
-          f"launches={eng.stats['launches']} syncs={eng.stats['syncs']}")
-    for r in reqs[:3]:
-        print(f"  req{r.rid}: {r.out}")
-    return eng.stats
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--lm", action="store_true",
-                    help="drive the token-level LM engine instead of the "
-                         "kernel service")
     ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--requests", type=int, default=None,
-                    help="request count (default: 48 kernel / 8 lm)")
-    # kernel-service mode
+    ap.add_argument("--requests", type=int, default=48,
+                    help="requests per wave")
     ap.add_argument("--backend", default="vector")
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--window-ms", type=float, default=2.0)
@@ -113,17 +72,7 @@ def main(argv=None):
                     help="restrict to these suite kernels")
     ap.add_argument("--json", default=None,
                     help="write the ServiceStats snapshot here")
-    # lm mode
-    ap.add_argument("--arch", default="qwen2-0.5b")
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--max-new", type=int, default=12)
-    ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--sync-always", action="store_true",
-                    help="HIP-CPU baseline policy (paper SVII-A.2)")
-    args = ap.parse_args(argv)
-    if args.requests is None:
-        args.requests = 8 if args.lm else 48
-    return serve_lm(args) if args.lm else serve_kernels(args)
+    return serve_kernels(ap.parse_args(argv))
 
 
 if __name__ == "__main__":

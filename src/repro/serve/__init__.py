@@ -1,13 +1,8 @@
-"""Serving tiers over the CuPBoP-JAX runtime.
+"""Serving over the CuPBoP-JAX runtime.
 
-Two granularities share the emit-on-hazard discipline:
-
-* :mod:`repro.serve.kernel_service` - the kernel-launch tier: multi-tenant
-  requests against registered suite kernels, batched into stacked
-  dispatches (``docs/serving.md``);
-* :mod:`repro.serve.engine` - the token-level LM tier: continuous-batching
-  decode over the transformer stack (imported lazily; it pulls in the
-  model code, which kernel-serving users never need).
+:mod:`repro.serve.kernel_service` is the kernel-launch tier: multi-tenant
+requests against registered suite kernels, batched into stacked dispatches
+(``docs/serving.md``).
 """
 from repro.serve.kernel_service import (
     Endpoint,

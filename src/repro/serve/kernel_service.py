@@ -46,10 +46,6 @@ throughput, warm-hit rate (compile-cache hit fraction since service
 start), batch-occupancy histogram, and queue depth - the JSON surface
 ``benchmarks/servebench.py`` feeds to the perf gate.  Each dispatch is a
 ``cupbop.serve.dispatch`` profiler span (attributes ``endpoint``, ``n``).
-
-The token-level LM tier (:mod:`repro.serve.engine`) sits beside this
-module: same emit-on-hazard discipline, different request granularity
-(decode steps vs kernel launches).
 """
 from __future__ import annotations
 

@@ -21,9 +21,6 @@ from repro.core.memory import (
     cuda_memcpy_d2h,
     cuda_memcpy_h2d,
 )
-from repro.distributed import compression
-from repro.models.common import cross_entropy
-from repro.models.padding import gqa_pad_plan
 
 SET = settings(max_examples=25, deadline=None)
 
@@ -375,54 +372,3 @@ def test_schedule_covers_all_blocks(grid, pool, grain):
     assert tr.n_fetches == -(-grid // grain)
     assert 0 < tr.utilization <= 1.0 + 1e-9
 
-
-# --- GQA padding plan invariants ---------------------------------------------
-@SET
-@given(hkv=st.integers(1, 48), r=st.integers(1, 8),
-       align=st.sampled_from([2, 4, 8, 16]))
-def test_pad_plan_invariants(hkv, r, align):
-    hq = hkv * r
-    plan = gqa_pad_plan(hq, hkv, align)
-    assert plan.hq_p % align == 0 and plan.hkv_p % align == 0
-    assert plan.hq_p == plan.hkv_p * plan.group_p
-    # every original q head appears exactly once
-    real_q = [m for m in plan.qmap if m >= 0]
-    assert sorted(real_q) == list(range(hq))
-    # q -> kv grouping preserved: padded q j maps to padded kv j//g whose
-    # original kv equals the original q's kv owner
-    for j, src in enumerate(plan.qmap):
-        if src < 0:
-            continue
-        kv_owner = plan.kvmap[j // plan.group_p]
-        assert kv_owner == src // r
-
-
-# --- compression ---------------------------------------------------------------
-@SET
-@given(seed=st.integers(0, 100),
-       scale=st.floats(1e-4, 1e4),
-       bits=st.sampled_from([4, 8]))
-def test_quantize_bounded(seed, scale, bits):
-    g = jnp.asarray(np.random.default_rng(seed)
-                    .standard_normal(128).astype(np.float32) * scale)
-    q, s = compression.quantize(g, bits)
-    err = np.abs(np.asarray(compression.dequantize(q, s) - g))
-    assert err.max() <= float(s) * 0.5 * 1.001 + 1e-12
-
-
-# --- loss ---------------------------------------------------------------------
-@SET
-@given(seed=st.integers(0, 50), vpad=st.integers(0, 7))
-def test_cross_entropy_vs_naive(seed, vpad):
-    rng = np.random.default_rng(seed)
-    V = 11
-    logits = rng.standard_normal((3, 5, V + vpad)).astype(np.float32)
-    logits[..., V:] = rng.standard_normal((3, 5, vpad)) * 10  # garbage pad
-    targets = rng.integers(0, V, (3, 5)).astype(np.int32)
-    ours = float(cross_entropy(jnp.asarray(logits), jnp.asarray(targets),
-                               real_vocab=V))
-    p = logits[..., :V]
-    p = p - p.max(-1, keepdims=True)
-    logp = p - np.log(np.exp(p).sum(-1, keepdims=True))
-    want = -np.take_along_axis(logp, targets[..., None], -1).mean()
-    np.testing.assert_allclose(ours, want, rtol=1e-4, atol=1e-5)

@@ -61,8 +61,19 @@ def _bits(x):
 # -------------------------------------------------------------------------
 # launch_batch: the stacked-dispatch primitive
 # -------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", ["loop", "vector"])
-@pytest.mark.parametrize("name", ["vecadd", "softmax_row", "reduce_shared"])
+SINGLE_LAUNCH = [e.name for e in build_suite(scale=1) if e.chain is None]
+# stacked float sums come out reordered under the batching vmap: a known
+# defect of the serving path (ROADMAP 3.7), held here until it is fixed
+BATCH_REORDERS = {("lavamd", "loop"), ("lavamd", "vector"),
+                  ("matmul_tiled", "loop")}
+
+
+@pytest.mark.parametrize("name,backend", [
+    pytest.param(name, backend, marks=pytest.mark.xfail(
+        strict=True, reason="launch_batch reorders float sums "
+                            "(ROADMAP 3.7)"))
+    if (name, backend) in BATCH_REORDERS else (name, backend)
+    for name in SINGLE_LAUNCH for backend in ("loop", "vector")])
 def test_launch_batch_bit_identical_to_independent(name, backend):
     entry = next(e for e in build_suite(scale=1) if e.name == name)
     rng = np.random.default_rng(0)

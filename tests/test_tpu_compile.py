@@ -3,7 +3,8 @@
 The TPU compiler is installed with JAX and compiles for a topology that is
 described, not attached.  These tests compile the ``vector`` lowering at
 deployment sizes (Rodinia's hotspot 1024x1024, nw 2048, pathfinder at
-102,400 columns, srad_v1's six kernels at 502x458) for one v5e chip, and pin the ``pallas`` rule: without
+102,400 columns, srad_v1's six kernels at 502x458) and every suite entry at
+its test size for one v5e chip, and pin the ``pallas`` rule: without
 interpret mode Mosaic refuses the suite's gather-mode kernels, and the
 launch path reports that refusal as ``UnsupportedKernel``.
 
@@ -19,9 +20,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import api, memory, packing
-from repro.core.cuda_suite import (entry_hotspot, entry_needle_nw,
-                                   entry_pathfinder, entry_srad_v1,
-                                   make_vecadd)
+from repro.core.cuda_suite import (build_suite, entry_hotspot,
+                                   entry_needle_nw, entry_pathfinder,
+                                   entry_srad_v1, make_vecadd)
 from repro.core.dim3 import Dim3
 from repro.core.kernel import UnsupportedKernel
 
@@ -107,6 +108,21 @@ def test_srad_v1_compiles_for_one_v5e_at_deployment_size(one_chip):
     assert schedules == {"extract": {"tiled"}, "prepare": {"tiled"},
                          "reduce": {"tiled"}, "srad": {"tiled"},
                          "srad2": {"tiled"}, "compress": {"tiled"}}
+
+
+@pytest.mark.parametrize("entry", build_suite(scale=1), ids=lambda e: e.name)
+def test_suite_compiles_for_one_v5e(entry, one_chip):
+    """Every launch specialization of each suite entry compiles for a v5e."""
+    args = entry.make_args(np.random.default_rng(0))
+    launches = ([(s.kernel, s.grid, s.block, s.dyn_shared)
+                 for s in entry.chain.all_steps] if entry.chain else
+                [(entry.kernel, entry.grid, entry.block, entry.dyn_shared)])
+    for kernel, grid, block, dyn_shared in launches:
+        ck = api.compiled(kernel, grid=grid, block=block, args=args,
+                          dyn_shared=dyn_shared, backend="vector")
+        compiled = ck.fn.lower(*_chip_shapes(kernel, args,
+                                             one_chip)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def test_pallas_mosaic_refusal_is_unsupported(one_chip):

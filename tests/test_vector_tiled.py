@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from repro.core import api, launch, lower_vector
+from repro.core import api, launch, lower_vector, memory
 from repro.core.cuda_suite import (
     SRAD_THREADS,
     build_suite,
@@ -30,27 +30,53 @@ from repro.core.kernel import KernelDef
 
 SUITE = build_suite(scale=1)
 
-# entries whose launches are not all serial for want of combines
-SCHEDULES = {
-    "bfs_frontier": {"serial: reads written buffer 'visited'"},
-    "pathfinder": {"tiled"},
+# the block schedule of each suite kernel, by kernel name; srad_v1's
+# in-place kernels and its reduction read only the elements they own,
+# kmeans's assign reads its own points but adds float sums across blocks
+KERNEL_SCHEDULES = {
+    "vecadd": "serial: no combines declared for ['c']",
+    "reverse": "serial: no combines declared for ['d']",
+    "histogram_coalesced": "serial: no combines declared for ['hist']",
+    "reduce_shared": "serial: no combines declared for ['out']",
+    "reduce_warp": "serial: no combines declared for ['out']",
+    "matmul_tiled": "serial: no combines declared for ['c']",
+    "stencil1d": "serial: no combines declared for ['y']",
+    "stencil2d": "serial: no combines declared for ['y']",
+    "softmax_row": "serial: no combines declared for ['y']",
+    "scan_block": "serial: no combines declared for ['y']",
+    "transpose_tiled": "serial: no combines declared for ['y']",
+    "pixel_pipeline": "serial: no combines declared for ['out']",
+    "bfs_frontier": "serial: reads written buffer 'visited'",
+    "pathfinder": "tiled",
     # the anti-diagonal comes from the ``diag`` buffer
-    "needle_nw": {
-        "serial: data-dependent coordinates into written buffer 'score'"},
-    "backprop_layer": {"tiled"},
-    "lud_diag": {"tiled"},
-    "srad_step": {"serial: float reduce_sum in the block", "tiled"},
-    "srad_v1": {"tiled: owned reads of ['I']",
-                "tiled: owned reads of ['sums', 'sums2']", "tiled"},
-    "lavamd": {"serial: float reduce_sum in the block"},
-    "nn": {"tiled"},
-    # assign reads its own points, then adds float sums across blocks;
-    # update reads and writes row c in block c
-    "kmeans": {"serial: float add into 'sumx'",
-               "tiled: owned reads of ['cx', 'cy']"},
-    "streamcluster": {"serial: reads written buffer 'dirty'"},
-    "hotspot": {"tiled"},
+    "needle_nw":
+        "serial: data-dependent coordinates into written buffer 'score'",
+    "backprop_layer": "tiled",
+    "lud_diag": "tiled",
+    "srad_stats": "tiled",
+    "srad_update": "serial: float reduce_sum in the block",
+    "extract": "tiled: owned reads of ['I']",
+    "prepare": "tiled",
+    "reduce": "tiled: owned reads of ['sums', 'sums2']",
+    "srad": "tiled",
+    "srad2": "tiled: owned reads of ['I']",
+    "compress": "tiled: owned reads of ['I']",
+    "lavamd": "serial: float reduce_sum in the block",
+    "nn_reduce": "tiled",
+    "nn_select": "tiled",
+    "kmeans_assign": "serial: float add into 'sumx'",
+    "kmeans_update": "tiled: owned reads of ['cx', 'cy']",
+    "streamcluster": "serial: reads written buffer 'dirty'",
+    "hotspot": "tiled",
 }
+
+
+def _launches(entry):
+    """(kernel, grid, block, dyn_shared) of each launch the entry makes."""
+    if entry.chain is None:
+        return [(entry.kernel, entry.grid, entry.block, entry.dyn_shared)]
+    return [(s.kernel, s.grid, s.block, s.dyn_shared)
+            for s in entry.chain.all_steps]
 
 
 @pytest.fixture
@@ -65,9 +91,7 @@ def _bits(out):
 
 
 def _schedules(entry):
-    if entry.name in SCHEDULES:
-        return SCHEDULES[entry.name]
-    return {f"serial: no combines declared for {list(entry.kernel.writes)}"}
+    return {KERNEL_SCHEDULES[k.name] for k, *_ in _launches(entry)}
 
 
 @pytest.mark.parametrize("entry", SUITE, ids=lambda e: e.name)
@@ -431,26 +455,19 @@ def test_cache_stats_count_each_specialization_once(fresh_cache):
             api.cache_stats().vector_serial) == (0, 0)
 
 
-# srad_v1's six kernels: the in-place ones and the reduction read only the
-# elements they own; prepare and srad write owned slices only
-SRAD_V1_SCHEDULES = {
-    "extract": "tiled: owned reads of ['I']",
-    "prepare": "tiled",
-    "reduce": "tiled: owned reads of ['sums', 'sums2']",
-    "srad": "tiled",
-    "srad2": "tiled: owned reads of ['I']",
-    "compress": "tiled: owned reads of ['I']",
-}
-
-
-def test_srad_v1_schedule_of_each_kernel(fresh_cache):
-    e = [e for e in SUITE if e.name == "srad_v1"][0]
-    run_entry(e, "vector", with_reference=False)
-    got = {}
-    for step in e.chain.all_steps:
-        for entry in step.kernel._launch_cache.values():
-            got.setdefault(step.kernel.name, set()).add(entry.schedule)
-    assert got == {k: {v} for k, v in SRAD_V1_SCHEDULES.items()}
+@pytest.mark.parametrize("entry,name", [
+    (e, name) for e in SUITE
+    for name in dict.fromkeys(k.name for k, *_ in _launches(e))],
+    ids=lambda p: p if isinstance(p, str) else p.name)
+def test_schedule_of_each_suite_kernel(entry, name, fresh_cache):
+    """Each launch of the kernel, bound as ``run_entry`` binds it, takes
+    the kernel's schedule."""
+    args = {k: memory.ConstArray(jnp.asarray(v)) if k in entry.const else v
+            for k, v in entry.make_args(np.random.default_rng(0)).items()}
+    got = {api.compiled(k, grid=g, block=b, args=args, backend="vector",
+                        dyn_shared=ds).schedule
+           for k, g, b, ds in _launches(entry) if k.name == name}
+    assert got == {KERNEL_SCHEDULES[name]}
 
 
 def test_launches_are_counted_by_schedule(fresh_cache):
